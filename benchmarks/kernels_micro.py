@@ -51,7 +51,7 @@ def run() -> list[Row]:
     k = jax.random.normal(jax.random.fold_in(key, 1), (B, L, KV, D))
     v = jax.random.normal(jax.random.fold_in(key, 2), (B, L, KV, D))
     vl = jnp.full((B,), L, jnp.int32)
-    us_k = _bench(decode_attention, q, k, v, vl, scale=0.125, block_k=128)
+    us_k = _bench(decode_attention, q, k, v, vl, scale=0.125, block_k=128, interpret=True)
     us_r = _bench(decode_attention_ref, q, k, v, vl, 0.125)
     csv_rows.append(["decode_attn", us_k, us_r])
     rows.append(("kernel_decode_attn", us_k, f"ref_us={us_r:.0f};interpret=True"))
@@ -61,7 +61,7 @@ def run() -> list[Row]:
     qr = jax.random.normal(jax.random.fold_in(key, 3), (B, 16, 16))
     ckv = jax.random.normal(jax.random.fold_in(key, 4), (B, L, 64))
     kr = jax.random.normal(jax.random.fold_in(key, 5), (B, L, 16))
-    us_k = _bench(mla_latent_decode, ql, qr, ckv, kr, vl, scale=0.11, block_l=128)
+    us_k = _bench(mla_latent_decode, ql, qr, ckv, kr, vl, scale=0.11, block_l=128, interpret=True)
     us_r = _bench(mla_latent_decode_ref, ql, qr, ckv, kr, vl, 0.11)
     mla = PAPER_MODELS["minitron-4b-mla"]()
     eager = resolve(emodel, decode_workload(mla, 1, 1024), Default())
@@ -80,7 +80,7 @@ def run() -> list[Row]:
     a = -jnp.exp(jnp.linspace(-2, 0.5, h))
     bm = jax.random.normal(jax.random.fold_in(key, 7), (b, s, n)) * 0.3
     cm = jax.random.normal(jax.random.fold_in(key, 8), (b, s, n)) * 0.3
-    us_k = _bench(ssd_prefill, x, dt, a, bm, cm, q_chunk=64, head_block=4)
+    us_k = _bench(ssd_prefill, x, dt, a, bm, cm, q_chunk=64, head_block=4, interpret=True)
     us_r = _bench(ssd_scan_ref, x, dt, a, bm, cm)
     m2 = PAPER_MODELS["mamba2-4b"]()
     e_eager = resolve(emodel, prefill_workload(m2, 1, 4096), Default()).energy_per_token_mj
@@ -99,7 +99,7 @@ def run() -> list[Row]:
     v2 = jax.random.normal(jax.random.fold_in(key, 10), (1, 128, 4, 32)) * 0.5
     beta = jax.nn.sigmoid(jax.random.normal(jax.random.fold_in(key, 11), (1, 128, 4)))
     alpha = jax.nn.sigmoid(jax.random.normal(jax.random.fold_in(key, 12), (1, 128, 4)) + 2)
-    us_k = _bench(gdn_prefill, q2, k2, v2, beta, alpha, q_chunk=32)
+    us_k = _bench(gdn_prefill, q2, k2, v2, beta, alpha, q_chunk=32, interpret=True)
     us_r = _bench(gdn_scan_ref, q2, k2, v2, beta, alpha)
     gdn = PAPER_MODELS["gdn-4b"]()
     e_eager = resolve(emodel, prefill_workload(gdn, 1, 4096), Default()).energy_per_token_mj

@@ -10,6 +10,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         fig1_roofline,
         fig2_heatmaps,

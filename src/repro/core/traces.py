@@ -60,6 +60,9 @@ class TracedRequest:
     conv: int = -1                      # conversation/tree id (-1: flat)
     parent: int = -1                    # trace index of the parent (-1: root)
     turn: int = 0                       # depth in the tree (root = 0)
+    # stop token override (None: the serving config's id). An id no token
+    # takes, e.g. -1, serves exactly max_new_tokens — fixed-length traffic
+    eos_token_id: Optional[int] = None
 
     @property
     def prompt_len(self) -> int:

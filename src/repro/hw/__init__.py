@@ -8,6 +8,8 @@ from repro.hw.chips import (
     HardwareSpec,
     TPU_V5E,
     H200_SXM,
+    DEVICE_KINDS,
+    chip_for_device_kind,
     get_chip,
 )
 from repro.hw.roofline import (
@@ -22,6 +24,8 @@ __all__ = [
     "HardwareSpec",
     "TPU_V5E",
     "H200_SXM",
+    "DEVICE_KINDS",
+    "chip_for_device_kind",
     "get_chip",
     "RooflineTerms",
     "roofline_terms",

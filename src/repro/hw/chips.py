@@ -159,10 +159,10 @@ H200_SXM = HardwareSpec(
 
 # --------------------------------------------------------------------------
 # TPU v5e — the target. Throughput ceilings per the task contract; power
-# surface is an explicit assumption set (documented in DESIGN.md §2): board
-# max ~220 W, idle floor ~11% of board max (H200 ratio), no firmware lock
-# clamp (clock locks are honoured exactly — a *difference* from the H200
-# that our benchmarks surface rather than hide).
+# surface is an explicit assumption set: board max ~220 W, idle floor ~11%
+# of board max (H200 ratio), no firmware lock clamp (clock locks are
+# honoured exactly — a *difference* from the H200 that our benchmarks
+# surface rather than hide).
 # --------------------------------------------------------------------------
 TPU_V5E = HardwareSpec(
     name="tpu-v5e",
@@ -192,9 +192,26 @@ TPU_V5E = HardwareSpec(
 
 _CHIPS = {c.name: c for c in (H200_SXM, TPU_V5E)}
 
+# ``jax.Device.device_kind`` -> the spec a run on that device is priced and
+# bounded with. A kind missing here is an error, never a default: a number
+# computed against the wrong chip's peaks is worse than none.
+DEVICE_KINDS = {
+    "TPU v5 lite": TPU_V5E,
+}
+
 
 def get_chip(name: str) -> HardwareSpec:
     try:
         return _CHIPS[name]
     except KeyError:
         raise KeyError(f"unknown chip {name!r}; have {sorted(_CHIPS)}") from None
+
+
+def chip_for_device_kind(kind: str) -> HardwareSpec:
+    """The ``HardwareSpec`` for a JAX ``device_kind`` string; raises
+    ``KeyError`` for a kind the table does not know."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise KeyError(f"no HardwareSpec for device kind {kind!r}; "
+                       f"have {sorted(DEVICE_KINDS)}") from None

@@ -14,8 +14,10 @@ Four kernels, each with <name>.py (pl.pallas_call + BlockSpec), ops.py
                 for the eager-mode prefill penalty)
 
 ``common.py`` holds the shared wrapper plumbing (tile clamping / padding).
-All kernels validate against their oracles in interpret mode on CPU; on
-real TPU pass interpret=False.
+Every kernel and wrapper compiles for the TPU by default
+(``interpret=False``); CPU callers (the oracle tests, the CPU micro
+benchmark) pass ``interpret=True``. ``tests/test_tpu_compile.py`` compiles
+each one for v5e at paper widths.
 """
 from repro.kernels.common import clamp_block, largest_divisor_block, pad_to_multiple
 from repro.kernels.decode_attn import (

@@ -7,9 +7,15 @@ while the (G, Dk) query tile and the (G, Dv) accumulator stay resident in
 VMEM scratch. Grid = (batch, kv_head, L/block_k); the KV-block axis is the
 innermost (sequential) dimension, so scratch carries the online-softmax
 state (m, l, acc) across blocks — the canonical TPU flash-decode schedule.
+Per-request valid lengths (and the paged block table) are scalar-prefetch
+operands in SMEM.
 
-Block shapes are MXU/VPU aligned: block_k is a multiple of 128 lanes; Dk/Dv
-land on the 128-lane minor dimension.
+Layout: the TPU tiles the last two dims of every block, so a block that
+takes ONE KV head must not have the head axis there. The model's caches
+are (.., rows, KV, D); both entry points transpose them to (.., KV, rows,
+D) before the call, making each block a (rows, D) tile. That transpose
+copies the cache once per call — acceptable while no served path calls
+these kernels; a head-major cache layout would remove it.
 """
 from __future__ import annotations
 
@@ -23,9 +29,14 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -2.3819763e38
 
 
-def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, block_k):
+def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+            scale, block):
+    """One KV tile of online-softmax attention for one (batch, KV head):
+    q (G, Dk) against k (block, Dk) / v (block, Dv). Tile j covers LOGICAL
+    positions [j*block, (j+1)*block) — for the paged kernel, whichever
+    physical page holds them — and positions >= the request's valid length
+    are masked."""
     j = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(j == 0)
     def _init():
@@ -34,20 +45,20 @@ def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, sca
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)               # (G, Dk)
-    k = k_ref[0, :, 0].astype(jnp.float32)            # (block_k, Dk)
-    v = v_ref[0, :, 0].astype(jnp.float32)            # (block_k, Dv)
+    k = k_ref[0, 0].astype(jnp.float32)               # (block, Dk)
+    v = v_ref[0, 0].astype(jnp.float32)               # (block, Dv)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * scale                                         # (G, block_k)
+    ) * scale                                         # (G, block)
 
-    kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(kpos < valid_ref[0], s, NEG_INF)
+    kpos = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(kpos < valid_ref[pl.program_id(0)], s, NEG_INF)
 
     m_prev = m_ref[...]                               # (G, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)                            # (G, block_k)
+    p = jnp.exp(s - m_new)                            # (G, block)
     corr = jnp.exp(m_prev - m_new)                    # (G, 1)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
@@ -56,51 +67,22 @@ def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, sca
     )
     m_ref[...] = m_new
 
-    @pl.when(j == nk - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_kernel(bt_ref, valid_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                  *, scale, block_size):
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
+def _paged_kernel(bt_ref, *refs, scale, block):
+    # the block table only steers the index maps; the body is the dense one
+    _kernel(*refs, scale=scale, block=block)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)               # (G, Dk)
-    k = k_ref[0, :, 0].astype(jnp.float32)            # (block_size, Dk)
-    v = v_ref[0, :, 0].astype(jnp.float32)            # (block_size, Dv)
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale                                         # (G, block_size)
-
-    # mask on LOGICAL position: block j of this request's table covers
-    # tokens [j*bs, (j+1)*bs) regardless of which physical page holds them.
-    # valid_ref is a whole-array scalar-prefetch operand: index by batch.
-    kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(kpos < valid_ref[pl.program_id(0)], s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+def _scratch(g: int, dv: int):
+    return [
+        pltpu.VMEM((g, 1), jnp.float32),
+        pltpu.VMEM((g, 1), jnp.float32),
+        pltpu.VMEM((g, dv), jnp.float32),
+    ]
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -112,7 +94,7 @@ def paged_decode_attention(
     valid_len: jax.Array,     # (B,) int32
     *,
     scale: float,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Flash decode over a PAGED cache: K/V pages are gathered through the
     per-request block table instead of assuming contiguous rows.
@@ -120,12 +102,11 @@ def paged_decode_attention(
     The table is a scalar-prefetch operand, so the page id is known before
     each grid step's DMA is issued — the (j -> block_tables[b, j]) indirection
     happens in the BlockSpec index map and the HBM->VMEM stream touches
-    exactly the pages the table names (the byte-accuracy the traffic meter
-    counts). Table entries past a request's last block point at page 0 (the
-    reserved null page); their rows are masked by ``valid_len`` like padding
-    in the dense kernel. Grid = (batch, kv_head, nb) with the logical-block
-    axis innermost carrying the online-softmax scratch, exactly like the
-    dense schedule.
+    exactly the pages the table names. Table entries past a request's last
+    block point at page 0 (the reserved null page); their rows are masked by
+    ``valid_len`` like padding in the dense kernel. Grid = (batch, kv_head,
+    nb) with the logical-block axis innermost carrying the online-softmax
+    scratch, exactly like the dense schedule.
     """
     b, h, dk = q.shape
     bs, kv = k_pages.shape[1], k_pages.shape[2]
@@ -134,27 +115,25 @@ def paged_decode_attention(
     g = h // kv
 
     qg = q.reshape(b, kv, g, dk)
+    kt = jnp.swapaxes(k_pages, 1, 2)                  # (P, KV, bs, Dk)
+    vt = jnp.swapaxes(v_pages, 1, 2)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,              # block table + valid lengths
         grid=(b, kv, nb),
         in_specs=[
             pl.BlockSpec((1, 1, g, dk), lambda bi, ki, j, bt, vl: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dk), lambda bi, ki, j, bt, vl: (bt[bi, j], 0, ki, 0)),
-            pl.BlockSpec((1, bs, 1, dv), lambda bi, ki, j, bt, vl: (bt[bi, j], 0, ki, 0)),
+            pl.BlockSpec((1, 1, bs, dk), lambda bi, ki, j, bt, vl: (bt[bi, j], ki, 0, 0)),
+            pl.BlockSpec((1, 1, bs, dv), lambda bi, ki, j, bt, vl: (bt[bi, j], ki, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv), lambda bi, ki, j, bt, vl: (bi, ki, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
-        ],
+        scratch_shapes=_scratch(g, dv),
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, block_size=bs),
+        functools.partial(_paged_kernel, scale=scale, block=bs),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, dv), q.dtype),
         interpret=interpret,
-    )(block_tables, valid_len, qg, k_pages, v_pages)
+    )(block_tables, valid_len, qg, kt, vt)
     return out.reshape(b, h, dv)
 
 
@@ -167,7 +146,7 @@ def decode_attention(
     *,
     scale: float,
     block_k: int = 512,
-    interpret: bool = True,  # CPU container: interpret; False on real TPU
+    interpret: bool = False,
 ) -> jax.Array:
     b, h, dk = q.shape
     l, kv = k.shape[1], k.shape[2]
@@ -177,23 +156,23 @@ def decode_attention(
     nk = l // block_k
 
     qg = q.reshape(b, kv, g, dk)
-    grid = (b, kv, nk)
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, block_k=block_k),
-        grid=grid,
+    kt = jnp.swapaxes(k, 1, 2)                        # (B, KV, L, Dk)
+    vt = jnp.swapaxes(v, 1, 2)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,              # valid lengths
+        grid=(b, kv, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, ki, j: (bi,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, dk), lambda bi, ki, j: (bi, ki, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, dk), lambda bi, ki, j: (bi, j, ki, 0)),
-            pl.BlockSpec((1, block_k, 1, dv), lambda bi, ki, j: (bi, j, ki, 0)),
+            pl.BlockSpec((1, 1, g, dk), lambda bi, ki, j, vl: (bi, ki, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, dk), lambda bi, ki, j, vl: (bi, ki, j, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda bi, ki, j, vl: (bi, ki, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dv), lambda bi, ki, j: (bi, ki, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, dv), lambda bi, ki, j, vl: (bi, ki, 0, 0)),
+        scratch_shapes=_scratch(g, dv),
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, block=block_k),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
-        ],
         interpret=interpret,
-    )(valid_len, qg, k, v)
+    )(valid_len, qg, kt, vt)
     return out.reshape(b, h, dv)

@@ -3,9 +3,8 @@
 ``gqa_decode_attention`` adapts the model's dense cache layout
 ((B, L, KV, hd) + per-request lengths) to the kernel and pads L to the
 block size; ``gqa_paged_decode_attention`` takes the paged layout
-((P, bs, KV, hd) pages + a per-request block table) as-is. On CPU
-containers the kernel bodies run in interpret mode; set
-``interpret=False`` on real TPU.
+((P, bs, KV, hd) pages + a per-request block table) as-is. Both compile
+for the TPU by default; CPU callers pass ``interpret=True``.
 """
 from __future__ import annotations
 
@@ -26,7 +25,7 @@ def gqa_decode_attention(
     *,
     scale: float,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     squeeze = q.ndim == 4
     if squeeze:
@@ -50,7 +49,7 @@ def gqa_paged_decode_attention(
     valid_len: jax.Array,     # (B,)
     *,
     scale: float,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Paged-cache flash decode: the model's block-table layout, unmodified.
 

@@ -17,8 +17,8 @@ def gdn_prefill(
     beta: jax.Array,    # (B, S, H)
     alpha: jax.Array,
     *,
-    q_chunk: int = 64,
-    interpret: bool = True,
+    q_chunk: int = 128,
+    interpret: bool = False,
 ):
     bsz, s, h, kd = q.shape
     q_chunk = clamp_block(q_chunk, s)

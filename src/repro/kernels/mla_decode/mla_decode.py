@@ -41,7 +41,7 @@ def _kernel(valid_ref, q_ref, kcat_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, 
         q, kcat, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                         # (H, block_l)
     kpos = j * block_l + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(kpos < valid_ref[0], s, NEG_INF)
+    s = jnp.where(kpos < valid_ref[pl.program_id(0)], s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -116,7 +116,7 @@ def mla_paged_latent_decode(
     valid_len: jax.Array,     # (B,)
     *,
     scale: float,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Absorbed-MLA latent decode over a PAGED compressed cache.
 
@@ -170,7 +170,7 @@ def mla_latent_decode(
     *,
     scale: float,
     block_l: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, h, rank = q_lat.shape
     rope = q_rope.shape[-1]
@@ -181,21 +181,24 @@ def mla_latent_decode(
     q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)            # (B,H,rank+rope)
     k_cat = jnp.concatenate([ckv, kr], axis=-1)                  # (B,L,rank+rope)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, block_l=block_l, rank=rank),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,              # valid lengths
         grid=(b, nl),
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, j: (bi,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, h, rank + rope), lambda bi, j: (bi, 0, 0)),
-            pl.BlockSpec((1, block_l, rank + rope), lambda bi, j: (bi, j, 0)),
+            pl.BlockSpec((1, h, rank + rope), lambda bi, j, vl: (bi, 0, 0)),
+            pl.BlockSpec((1, block_l, rank + rope), lambda bi, j, vl: (bi, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, rank), lambda bi, j: (bi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, rank), q_lat.dtype),
+        out_specs=pl.BlockSpec((1, h, rank), lambda bi, j, vl: (bi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, rank), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, block_l=block_l, rank=rank),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), q_lat.dtype),
         interpret=interpret,
     )(valid_len, q_cat, k_cat)
     return out

@@ -31,7 +31,7 @@ def mla_fused_decode(
     *,
     scale: float,
     block_l: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:            # (B, d)
     blk = clamp_block(block_l, ckv.shape[1])
     ckv = pad_to_multiple(ckv, blk, axis=1)
@@ -58,7 +58,7 @@ def mla_paged_fused_decode(
     valid_len: jax.Array,     # (B,)
     *,
     scale: float,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:               # (B, d)
     """Full absorbed decode step over the PAGED latent cache: absorb(w_uk)
     -> paged latent kernel -> absorb(w_uv) -> w_o. No padding — the page

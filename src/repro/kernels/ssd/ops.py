@@ -23,7 +23,7 @@ def ssd_prefill(
     *,
     q_chunk: int = 128,
     head_block: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     bsz, s, h, p = x.shape
     q_chunk = clamp_block(q_chunk, s)

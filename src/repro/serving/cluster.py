@@ -95,7 +95,7 @@ class Cluster:
         cls,
         spec: ReplicaSpec,
         *,
-        emodel=None,
+        emodel,
         params: Any = None,
         clock: Callable[[], float] = time.perf_counter,
         meter_interval_s: float = 0.050,

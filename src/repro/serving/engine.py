@@ -77,7 +77,7 @@ class ServingEngine:
         cls,
         spec: ReplicaSpec,
         *,
-        emodel=None,
+        emodel,
         params: Any = None,
         clock: Callable[[], float] = time.perf_counter,
     ) -> "ServingEngine":
@@ -88,11 +88,8 @@ class ServingEngine:
         import jax
 
         from repro.configs import get_config, reduced_config
-        from repro.core.energy import EnergyModel
-        from repro.hw import H200_SXM
         from repro.models import init_params
 
-        emodel = emodel if emodel is not None else EnergyModel(H200_SXM)
         full = get_config(spec.arch)
         cfg = reduced_config(spec.arch) if spec.reduced else full
         if params is None:

@@ -107,7 +107,8 @@ import numpy as np
 
 from repro.models import shard_map_replicas, vmap_replicas
 from repro.serving.pool import (
-    BankRow, CacheBank, Pool, Request, observe_latencies, requeue_front,
+    BankRow, CacheBank, Pool, Request, row_on_device, observe_latencies,
+    requeue_front,
 )
 
 BATCH_LAYOUTS = ("vmap", "shard_map")
@@ -147,17 +148,53 @@ def _program(key: Tuple[Any, ...], make: Callable[[], Any]):
     return fn
 
 
-def _batched_core(impl, layout: str, p2: int):
-    """The replica-batched decode body: params broadcast, everything else
-    stacked along the leading replica axis. ``shard_map`` lays the batch
-    over the host's devices when the padded size divides them; otherwise
-    (including the 1-device case, where the mesh would be trivial anyway)
-    plain ``vmap``. Module-level so the process-wide program cache never
-    retains an engine through the traced closure."""
+def _host_mesh(layout: str, p2: int) -> Optional[Tuple[Any, ...]]:
+    """The devices an unbound fused group of padded size ``p2`` shards its
+    replica axis over: all of the host's, when ``layout`` asks for
+    ``shard_map`` and ``p2`` divides evenly over more than one device;
+    otherwise None, and the group runs as plain ``vmap`` on one device
+    (``EngineStats.vmap_fallbacks`` counts those under ``shard_map``)."""
     n_dev = len(jax.devices())
     if layout == "shard_map" and n_dev > 1 and p2 % n_dev == 0:
-        return shard_map_replicas(impl, 7)
-    return vmap_replicas(impl, 7)
+        return tuple(jax.devices())
+    return None
+
+
+def _batched_core(impl, devices: Optional[Tuple[Any, ...]]):
+    """The replica-batched decode body: params broadcast, everything else
+    stacked along the leading replica axis — ``shard_map`` over
+    ``devices`` (row blocks in device order), or plain ``vmap`` when None.
+    Module-level so the process-wide program cache never retains an engine
+    through the traced closure."""
+    if devices is None:
+        return vmap_replicas(impl, 7)
+    return shard_map_replicas(impl, 7, devices=list(devices))
+
+
+def _stack_decode_args(pres: List[dict], pad: int):
+    """A fused chunk's dense decode arguments (tokens, lengths, active,
+    RNG keys, temperatures) stacked along a leading replica axis, padded
+    with ``pad`` inert repeats of member 0. Keys stay a tuple: they stack
+    inside the program."""
+    rows = [pre["args"] for pre in pres]
+    rows += [rows[0]] * pad
+    stack = lambda i: np.stack([a[i] for a in rows])
+    return stack(1), stack(3), stack(4), tuple(a[5] for a in rows), stack(6)
+
+
+def _bank_step_program(impl, devices: Optional[Tuple[Any, ...]], p2: int):
+    """The coherent-bank batched decode program: the stacked cache is
+    donated and the output tree replaces it, so a stable group pays no
+    stack/unstack work. ``devices`` as in ``_batched_core``."""
+    def make():
+        def fused(params, cache, toks, lengths, active, keys, temps):
+            core = _batched_core(impl, devices)
+            return core(params, toks, cache, lengths, active,
+                        jnp.stack(keys), temps)
+
+        return jax.jit(fused, donate_argnums=(1,))
+
+    return _program(("decode_batched", impl, devices, p2), make)
 
 
 @dataclasses.dataclass(slots=True)
@@ -181,6 +218,12 @@ class EngineStats:
                                        # ONE vmap/shard_map-batched program
                                        # (subset of fused_decode_calls)
     batched_prefill_calls: int = 0     # ditto for fused admission prefill
+    shard_map_calls: int = 0           # batched decode dispatches laid out
+                                       # over a device mesh with shard_map
+    vmap_fallbacks: int = 0            # batched decode dispatches that asked
+                                       # for shard_map but ran as vmap on one
+                                       # device (group size not a multiple
+                                       # of the host's device count)
     bank_gathers: int = 0              # churned groups re-stacked by an
                                        # in-program index gather off ONE
                                        # still-resident bank (cheap)
@@ -277,6 +320,13 @@ class EventDrivenFleet:
         # and the opt-out flag for shapes where per-replica tracing wins
         self.batch_replicas = bool(batch_replicas)
         self.batch_layout = batch_layout
+        # device-bound pools (``Fleet.from_spec(devices=...)``) under
+        # shard_map fuse ACROSS devices: one program over a mesh of their
+        # devices, each replica's bank row on its own device
+        self._mesh = self.batch_replicas and batch_layout == "shard_map"
+        # replicated weights assembled from the members' per-device copies,
+        # keyed (params_token, devices)
+        self._mesh_params: Dict[Tuple[Any, ...], Any] = {}
         self.time_dispatch = bool(time_dispatch)
         self.on_finish = on_finish
         self.stats = EngineStats()
@@ -510,7 +560,8 @@ class EventDrivenFleet:
                 r.advance_all(t)
             self._autoscale()
         req = fleet.submit(tr.prompt, tr.max_new_tokens,
-                           temperature=tr.temperature, arrival_s=t,
+                           temperature=tr.temperature,
+                           eos_token_id=tr.eos_token_id, arrival_s=t,
                            bucket=tr.bucket)
         r = fleet.by_name[req.replica]
         if r._warming_until_s is not None and t < r._warming_until_s - _EPS:
@@ -677,8 +728,9 @@ class EventDrivenFleet:
                 continue
             toks, true_len, bucket = pp.prefill_tokens(req)
             # params_token (not id(params)): a stable monotonic identity
-            # that a GC'd fleet can never hand to a different pool's weights
-            sig = (pp.cfg, pp.params_token, pp.max_seq_len, bucket)
+            # that a GC'd fleet can never hand to a different pool's weights;
+            # the device keeps a group's rows (and weights) on one chip
+            sig = (pp.cfg, pp.params_token, pp.max_seq_len, bucket, pp.device)
             g = groups.get(sig)
             if g is None:
                 g = groups[sig] = []
@@ -869,8 +921,11 @@ class EventDrivenFleet:
         groups: Dict[Tuple[Any, ...], List["Replica"]] = {}
         for r in live:
             dp = r.decode_pool
+            # device-bound pools fuse within their device, or across
+            # devices under the mesh layout
+            dev = None if self._mesh else dp.device
             sig = (dp.cfg.name, dp.params_token, dp.paged, dp.max_batch,
-                   dp.max_seq_len)
+                   dp.max_seq_len, dev)
             groups.setdefault(sig, []).append(r)
         for sig, rs in groups.items():
             if not sig[2] and len(rs) >= self.fast_path_min:
@@ -908,11 +963,14 @@ class EventDrivenFleet:
         else:
             pres = [p._decode_begin() for p in pools]
         finished: Dict[str, List[Request]] = {}
-        for i in range(0, len(reps), self.max_fused_group):
-            chunk_pools = pools[i:i + self.max_fused_group]
-            chunk_pres = pres[i:i + self.max_fused_group]
+        for chunk in self._decode_chunks(pools):
+            chunk_pools = [pools[j] for j in chunk]
+            chunk_pres = [pres[j] for j in chunk]
             t0 = time.perf_counter() if self.time_dispatch else 0.0
-            if self.batch_replicas:
+            if self._mesh and chunk_pools[0].device is not None:
+                outs, p2 = self._decode_chunk_mesh(sig, chunk_pools,
+                                                   chunk_pres)
+            elif self.batch_replicas:
                 outs, p2 = self._decode_chunk_batched(sig, chunk_pools,
                                                       chunk_pres)
             else:
@@ -925,10 +983,29 @@ class EventDrivenFleet:
                 ent[1] += time.perf_counter() - t0
             st.fused_decode_calls += 1
             st.pad_waste += p2 - len(chunk_pools)
-            for r, p, pre, out in zip(reps[i:i + self.max_fused_group],
-                                      chunk_pools, chunk_pres, outs):
-                finished[r.name] = p._decode_finish(pre, *out)
+            for j, pre, out in zip(chunk, chunk_pres, outs):
+                finished[reps[j].name] = pools[j]._decode_finish(pre, *out)
         return finished
+
+    def _decode_chunks(self, pools: List[Pool]) -> List[List[int]]:
+        """Index chunks of a fused group: runs of ``max_fused_group``; for
+        device-bound pools under the mesh layout, chunks of distinct
+        devices in device order (one bank row per device, and a stable
+        member order keeps the bank coherent between steps)."""
+        n = len(pools)
+        if not (self._mesh and pools[0].device is not None):
+            return [list(range(i, min(i + self.max_fused_group, n)))
+                    for i in range(0, n, self.max_fused_group)]
+        chunks: List[List[int]] = []
+        for j in sorted(range(n), key=lambda j: pools[j].device.id):
+            for c in chunks:
+                if (len(c) < self.max_fused_group
+                        and all(pools[i].device != pools[j].device for i in c)):
+                    c.append(j)
+                    break
+            else:
+                chunks.append([j])
+        return chunks
 
     def _decode_chunk_tuple(self, sig, pools: List[Pool],
                             pres: List[dict]) -> Tuple[List[Any], int]:
@@ -1016,38 +1093,19 @@ class EventDrivenFleet:
         pad = p2 - k
         # dense _decode_begin args: (params, toks, cache, lengths, active,
         # key, temps) — stack everything but params/cache as host numpy
-        argrows = [pre["args"] for pre in pres]
-        toks = np.stack([a[1] for a in argrows]
-                        + [argrows[0][1]] * pad)
-        lengths = np.stack([a[3] for a in argrows]
-                           + [argrows[0][3]] * pad)
-        active = np.stack([a[4] for a in argrows]
-                          + [argrows[0][4]] * pad)
-        keys = tuple(a[5] for a in argrows) + (argrows[0][5],) * pad
-        temps = np.stack([a[6] for a in argrows]
-                         + [argrows[0][6]] * pad)
+        toks, lengths, active, keys, temps = _stack_decode_args(pres, pad)
         pool0 = pools[0]
-        layout = self.batch_layout
+        devs = _host_mesh(self.batch_layout, p2)
+        if self.batch_layout == "shard_map":
+            if devs is None:
+                st.vmap_fallbacks += 1
+            else:
+                st.shard_map_calls += 1
         bank = self._bank_coherent(pools, p2)
 
         if bank is not None:
-            def build(pool0=pool0):
-                impl = pool0._decode_impl
-
-                def make():
-                    def fused(params, cache, toks, lengths, active, keys,
-                              temps):
-                        kstack = jnp.stack(keys)
-                        core = _batched_core(impl, layout, p2)
-                        return core(params, toks, cache, lengths, active,
-                                    kstack, temps)
-
-                    # donate the stacked cache: the bank swaps in the output
-                    return jax.jit(fused, donate_argnums=(1,))
-
-                return _program(("decode_batched", impl, layout, p2), make)
-
-            fn = self._fused_fn(("decode", sig, p2), build)
+            fn = self._fused_fn(("decode", sig, p2), lambda: _bank_step_program(
+                pool0._decode_impl, devs, p2))
             next_tok, new_tree, new_lengths = fn(
                 pool0.params, bank.tree, toks, lengths, active, keys, temps)
             bank.tree = new_tree
@@ -1063,7 +1121,7 @@ class EventDrivenFleet:
                               keys, temps):
                         cache = jax.tree.map(lambda x: x[rows], src_tree)
                         kstack = jnp.stack(keys)
-                        core = _batched_core(impl, layout, p2)
+                        core = _batched_core(impl, devs)
                         return core(params, toks, cache, lengths, active,
                                     kstack, temps)
 
@@ -1072,7 +1130,7 @@ class EventDrivenFleet:
                     return jax.jit(fused)
 
                 return _program(
-                    ("decode_batched_gather", impl, layout, p2, src_size),
+                    ("decode_batched_gather", impl, devs, p2, src_size),
                     make)
 
             fn = self._fused_fn(("decode_gather", sig, p2, src.size), build)
@@ -1127,14 +1185,14 @@ class EventDrivenFleet:
                               temps):
                         cache = jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
                         kstack = jnp.stack(keys)
-                        core = _batched_core(impl, layout, p2)
+                        core = _batched_core(impl, devs)
                         return core(params, toks, cache, lengths, active,
                                     kstack, temps)
 
                     # no donation: pad rows alias row 0
                     return jax.jit(fused)
 
-                return _program(("decode_batched_restack", impl, layout, p2),
+                return _program(("decode_batched_restack", impl, devs, p2),
                                 make)
 
             fn = self._fused_fn(("decode_restack", sig, p2), build)
@@ -1149,6 +1207,64 @@ class EventDrivenFleet:
         return [(next_np[j], BankRow(bank, j), len_np[j])
                 for j in range(k)], p2
 
+    def _decode_chunk_mesh(self, sig, pools: List[Pool],
+                           pres: List[dict]) -> Tuple[List[Any], int]:
+        """ONE shard_map program for device-bound pools on distinct
+        devices: the mesh is exactly their devices, and row j of the
+        stacked bank lives on pool j's device. No padding: the mesh takes
+        the group's size. A coherent bank (same members as the last step)
+        is donated and replaced as on one device; otherwise the bank is
+        assembled from where each row already lives — the member's shard
+        of an earlier bank (no copy) or its own dense cache — so no row and
+        no weight ever crosses devices. The weights are the members'
+        per-device copies, assembled into one replicated array."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+        st = self.stats
+        k = len(pools)
+        devs = tuple(p.device for p in pools)
+        mesh = Mesh(np.asarray(devs), ("replica",))
+        toks, lengths, active, keys, temps = _stack_decode_args(pres, 0)
+        pool0 = pools[0]
+        bank = self._bank_coherent(pools, k)
+        if bank is None:
+            rows = [self._mesh_row(p) for p in pools]
+            stacked = NamedSharding(mesh, PartitionSpec("replica"))
+            bank = CacheBank(jax.tree.map(
+                lambda *xs: jax.make_array_from_single_device_arrays(
+                    (k,) + xs[0].shape[1:], stacked, list(xs)), *rows), k)
+            st.bank_rebuilds += 1
+        pkey = (pool0.params_token, devs)
+        params = self._mesh_params.get(pkey)
+        if params is None:
+            replicated = NamedSharding(mesh, PartitionSpec())
+            params = self._mesh_params[pkey] = jax.tree.map(
+                lambda *xs: jax.make_array_from_single_device_arrays(
+                    xs[0].shape, replicated, list(xs)),
+                *[p.params for p in pools])
+        fn = self._fused_fn(("decode_mesh", sig, devs), lambda: _bank_step_program(
+            pool0._decode_impl, devs, k))
+        next_tok, bank.tree, new_lengths = fn(
+            params, bank.tree, toks, lengths, active, keys, temps)
+        st.batched_decode_calls += 1
+        st.shard_map_calls += 1
+        next_np = np.asarray(next_tok)
+        len_np = np.asarray(new_lengths)
+        return [(next_np[j], BankRow(bank, j), len_np[j])
+                for j in range(k)], k
+
+    @staticmethod
+    def _mesh_row(pool: Pool):
+        """A bound pool's cache as a one-row stack on its own device: its
+        shard of the mesh bank it last stepped in (mesh banks hold one row
+        per device), or its dense cache grown a leading axis — donated, so
+        the row takes over the cache's buffer instead of a second copy."""
+        c = pool.cache
+        if isinstance(c, BankRow):
+            return jax.tree.map(
+                lambda x: row_on_device(x, c.index, pool.device)[0], c.bank.tree)
+        return _program(("row_expand",), lambda: jax.jit(
+            lambda t: jax.tree.map(lambda x: x[None], t), donate_argnums=(0,)))(c)
 
     # ------------------------------------------------------ warm / autoscaler
     def _schedule_warm(self, r: "Replica"):

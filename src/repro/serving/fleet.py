@@ -51,7 +51,9 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from typing import Any, Callable, Deque, Dict, Iterable, List, Mapping, Optional, Tuple
+from typing import (
+    Any, Callable, Deque, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple,
+)
 
 import numpy as np
 
@@ -189,9 +191,13 @@ class Replica:
         kv_block_size: int = 16,
         kv_blocks: Optional[int] = None,
         prefix_sharing: bool = False,
+        device: Any = None,
     ):
         self.cfg = cfg
         self.name = name
+        # the device both pools are bound to (None: JAX's default device);
+        # the pools hold that device's copy of ``params``
+        self.device = device
         self.arch = cfg.name
         # per-pool timelines: ``clock`` is the decode pool's (and the
         # replica's reference clock); ``prefill_clock`` defaults to the same
@@ -206,7 +212,7 @@ class Replica:
             cfg, params, role="prefill", max_batch=max(1, prefill_batch),
             max_seq_len=max_seq_len, rng_seed=rng_seed,
             clock=self.prefill_clock,
-            meter_interval_s=meter_interval_s,
+            meter_interval_s=meter_interval_s, device=device,
         )
         # only the decode pool pages its cache: prefill is batch-1 scratch
         # whose row is handed off (copy-on-migrate) at admission
@@ -215,7 +221,7 @@ class Replica:
             max_seq_len=max_seq_len, rng_seed=rng_seed, clock=clock,
             meter_interval_s=meter_interval_s,
             paged=paged, kv_block_size=kv_block_size, kv_blocks=kv_blocks,
-            prefix_sharing=prefix_sharing,
+            prefix_sharing=prefix_sharing, device=device,
         )
         self.controller = controller
         self.scheduler = Scheduler(prefill_chunk_tokens)
@@ -249,25 +255,25 @@ class Replica:
         cls,
         spec: ReplicaSpec,
         *,
-        emodel=None,
+        emodel,
         clock: Callable[[], float] = time.perf_counter,
         prefill_clock: Optional[Callable[[], float]] = None,
         params: Any = None,
         meter_interval_s: float = 0.050,
+        device: Any = None,
     ) -> "Replica":
-        """Build a live replica from a declarative spec. ``params`` may be
-        shared across replicas of the same arch; when omitted they are
-        initialised from ``spec.rng_seed``. The controller's policy table
-        always resolves the FULL config; ``spec.reduced`` only picks the
-        config the pools execute."""
+        """Build a live replica from a declarative spec. ``emodel`` is the
+        energy model of the chip the replica is priced as — there is no
+        default chip. ``params`` may be shared across replicas of the same
+        arch; when omitted they are initialised from ``spec.rng_seed``.
+        ``device`` binds both pools to one device (see ``Pool``). The
+        controller's policy table always resolves the FULL config;
+        ``spec.reduced`` only picks the config the pools execute."""
         import jax
 
         from repro.configs import get_config, reduced_config
-        from repro.core.energy import EnergyModel
-        from repro.hw import H200_SXM
         from repro.models import init_params
 
-        emodel = emodel if emodel is not None else EnergyModel(H200_SXM)
         full = get_config(spec.arch)
         cfg = reduced_config(spec.arch) if spec.reduced else full
         if params is None:
@@ -289,6 +295,7 @@ class Replica:
             kv_block_size=spec.decode.kv_block_size,
             kv_blocks=spec.decode.kv_blocks,
             prefix_sharing=spec.decode.prefix_sharing,
+            device=device,
         )
 
     # ------------------------------------------------------------------ api
@@ -575,17 +582,23 @@ class Fleet:
         cls,
         spec: FleetSpec,
         *,
-        emodel=None,
+        emodel,
         clock: Optional[Callable[[], float]] = None,
         params_for: Optional[Mapping[str, Any]] = None,
         meter_interval_s: float = 0.050,
+        devices: Optional[Sequence[Any]] = None,
     ) -> "Fleet":
         """Build N live replicas + the router from a declarative spec.
 
+        ``emodel`` prices every replica (there is no default chip).
         ``clock`` defaults to a fresh ``VirtualClock`` (the fleet harness is
         trace-replay-first); ``params_for`` maps arch name -> params so
         same-arch replicas (and repeated builds in a benchmark) can share
-        one initialisation instead of paying it per replica.
+        one initialisation instead of paying it per replica. ``devices``
+        binds replica i to ``devices[i % len(devices)]`` (a multi-chip
+        host: one replica per chip); the shared params must then be
+        replicated over those devices. Without it every replica runs on
+        JAX's default device.
         """
         if clock is None:
             # TWO VirtualClocks per replica — decode and prefill are
@@ -602,8 +615,9 @@ class Fleet:
                 rs, emodel=emodel, clock=c, prefill_clock=pc,
                 params=params_for.get(rs.arch),
                 meter_interval_s=meter_interval_s,
+                device=devices[i % len(devices)] if devices else None,
             )
-            for rs, (c, pc) in zip(spec.replicas, clock_pairs)
+            for i, (rs, (c, pc)) in enumerate(zip(spec.replicas, clock_pairs))
         ]
         return cls(
             replicas,
@@ -959,6 +973,7 @@ class Fleet:
                     i += 1
                     self.submit(t.prompt, t.max_new_tokens,
                                 temperature=t.temperature,
+                                eos_token_id=t.eos_token_id,
                                 arrival_s=t_start + t.arrival_s,
                                 bucket=t.bucket)
                 if not self.busy():

@@ -206,6 +206,52 @@ def decode_paged_impl_for(cfg: ModelConfig):
     return _cached(("decode_paged_impl", cfg), build)
 
 
+def decode_jit_for(cfg: ModelConfig, paged: bool = False):
+    """The jitted one-step decode program a pool dispatches on its serial
+    path (dense or paged). The cache argument is donated: the step writes
+    the new cache over the old one instead of holding two copies, which
+    at full width is the difference between fitting one chip or not."""
+    if paged:
+        return _cached(("decode_paged_jit", cfg), lambda: jax.jit(
+            decode_paged_impl_for(cfg), donate_argnums=(2,)))
+    return _cached(("decode_jit", cfg), lambda: jax.jit(
+        decode_impl_for(cfg), donate_argnums=(2,)))
+
+
+def params_on_device(params: Any, device: Any) -> Any:
+    """The copy of ``params`` that ``device`` holds, without copying: each
+    leaf must be committed to ``device`` or replicated over devices that
+    include it (``jax.device_put`` onto a replicated ``NamedSharding``, or
+    ``init_params_jit`` with one). ``device=None`` returns ``params``."""
+    if device is None:
+        return params
+
+    def local(x):
+        for shard in getattr(x, "addressable_shards", ()):
+            if shard.device == device and shard.data.shape == x.shape:
+                return shard.data
+        raise ValueError(
+            f"a weight of shape {getattr(x, 'shape', None)} has no whole copy "
+            f"on {device}: replicate the params over the fleet's devices")
+
+    return jax.tree.map(local, params)
+
+
+def row_on_device(leaf: jax.Array, row: int, device: Any) -> Tuple[jax.Array, int]:
+    """(single-device array, offset) holding replica row ``row`` of a
+    stacked bank leaf on ``device`` — the whole leaf for a one-device bank,
+    that device's shard for a bank sharded over the replica axis."""
+    for shard in leaf.addressable_shards:
+        if shard.device != device:
+            continue
+        sl = shard.index[0] if shard.index else slice(None)
+        start = sl.start or 0
+        stop = leaf.shape[0] if sl.stop is None else sl.stop
+        if start <= row < stop:
+            return shard.data, row - start
+    raise ValueError(f"bank row {row} is not on {device}")
+
+
 def _scatter_impl(big_cache, small_cache, slot):
     # stage-cache leaves are stacked (n_units, B, ...): batch axis is 1
     return jax.tree.map(
@@ -546,13 +592,20 @@ class Pool:
         kv_block_size: int = 16,
         kv_blocks: Optional[int] = None,   # default: dense-equivalent budget
         prefix_sharing: bool = False,
+        device: Any = None,
     ):
         self.cfg = cfg
-        self.params = params
+        # device binding (multi-device hosts): a bound pool keeps its cache
+        # on ``device`` and runs its own programs against the copy of the
+        # weights that device holds; ``None`` leaves placement to JAX (the
+        # default device). See ``params_on_device``.
+        self.device = device
+        self.params = params_on_device(params, device)
         # stable weights-identity token for fused-dispatch grouping: pools
-        # constructed over the SAME params object share it; a freed-and-
-        # rebuilt fleet can never collide with this one (monotonic counter,
-        # never recycled — unlike id(params))
+        # constructed over the SAME params object share it — per-device
+        # views of one replicated params included; a freed-and-rebuilt
+        # fleet can never collide with this one (monotonic counter, never
+        # recycled — unlike id(params))
         self.params_token = params_token_for(params)
         self.role = role
         self.max_batch = max_batch
@@ -660,10 +713,8 @@ class Pool:
         self._jit_prefill = _cached(
             ("prefill_jit", cfg, max_seq_len),
             lambda: jax.jit(self._prefill_impl, static_argnames=("bucket",)))
-        self._jit_decode = _cached(
-            ("decode_jit", cfg), lambda: jax.jit(self._decode_impl))
-        self._jit_decode_paged = _cached(
-            ("decode_paged_jit", cfg), lambda: jax.jit(self._decode_paged_impl))
+        self._jit_decode = decode_jit_for(cfg)
+        self._jit_decode_paged = decode_jit_for(cfg, paged=True)
         self._jit_scatter = _cached(
             ("scatter_jit",), lambda: jax.jit(_scatter_impl, donate_argnums=(0,)))
         if paged:
@@ -763,7 +814,7 @@ class Pool:
     def set_params(self, params: Any) -> None:
         """Swap this pool's weights and refresh ``params_token`` so fused
         grouping immediately reflects the new identity."""
-        self.params = params
+        self.params = params_on_device(params, self.device)
         self.params_token = params_token_for(params)
 
     # ----------------------------------------------------- bank-view cache
@@ -778,8 +829,37 @@ class Pool:
         row = self.cache
         fn = _cached(("bank_row_jit",),
                      lambda: jax.jit(_bank_row_impl))
-        self.cache = fn(row.bank.tree, np.int32(row.index))
+        if self.device is None:
+            self.cache = fn(row.bank.tree, np.int32(row.index))
+        else:
+            # gather on this pool's device, from the shard holding its row
+            self.cache = jax.tree.map(
+                lambda x: fn(*row_on_device(x, row.index, self.device)),
+                row.bank.tree)
         self.jit_dispatches += 1
+
+    def _bank_write(self, impl_key: Tuple, impl: Callable, rows: Any,
+                    slots: Any) -> None:
+        """Scatter prefilled ``rows`` into ``slots`` of this pool's bank row
+        with the donating ``impl(tree, rows, row, slots)``. Unbound pools
+        scatter into the whole stacked tree; a bound pool scatters into the
+        shard that holds its row, on its own device, and the bank is
+        reassembled around the new shard — no row leaves its device."""
+        view = self.cache
+        fn = _cached(impl_key, lambda: jax.jit(impl, donate_argnums=(0,)))
+        if self.device is None:
+            view.bank.tree = fn(view.bank.tree, rows, np.int32(view.index), slots)
+            return
+        leaves, treedef = jax.tree.flatten(view.bank.tree)
+        local = [row_on_device(x, view.index, self.device) for x in leaves]
+        others = [[s.data for s in x.addressable_shards if s.device != self.device]
+                  for x in leaves]
+        new = jax.tree.leaves(fn(treedef.unflatten([a for a, _ in local]),
+                                 rows, np.int32(local[0][1]), slots))
+        view.bank.tree = treedef.unflatten([
+            jax.make_array_from_single_device_arrays(x.shape, x.sharding, o + [n])
+            if o else n
+            for x, n, o in zip(leaves, new, others)])
 
     # ------------------------------------------------------- energy plumbing
     def set_operating_point(self, op: OperatingPoint, prefill_op: Optional[OperatingPoint] = None):
@@ -903,13 +983,16 @@ class Pool:
     def _ensure_decode_state(self):
         if self.cache is None:
             if self.paged:
-                self.cache = init_paged_cache(
+                make = lambda: init_paged_cache(
                     self.cfg, self.max_batch,
                     self.allocator.num_blocks + 1,   # + the null page
                     self.kv_block_size,
                 )
             else:
-                self.cache = init_cache(self.cfg, self.max_batch, self.max_seq_len)
+                make = lambda: init_cache(self.cfg, self.max_batch, self.max_seq_len)
+            # a bound pool's cache is born on its device, never copied there
+            self.cache = make() if self.device is None else jax.jit(
+                make, out_shardings=jax.sharding.SingleDeviceSharding(self.device))()
             self.lengths = jnp.zeros((self.max_batch,), jnp.int32)
             self.cur_token = jnp.zeros((self.max_batch,), jnp.int32)
 
@@ -1330,12 +1413,8 @@ class Pool:
         elif isinstance(self.cache, BankRow):
             # write THROUGH the bank: the stacked tree is donated and
             # replaced, so every other member pool's view follows along
-            row = self.cache
-            fn = _cached(("bank_scatter_jit",),
-                         lambda: jax.jit(_bank_scatter_impl,
-                                         donate_argnums=(0,)))
-            row.bank.tree = fn(row.bank.tree, cache1,
-                               np.int32(row.index), np.int32(slot))
+            self._bank_write(("bank_scatter_jit",), _bank_scatter_impl,
+                             cache1, np.int32(slot))
         else:
             self.cache = self._jit_scatter(self.cache, cache1, slot)
         self.jit_dispatches += 1
@@ -1364,12 +1443,9 @@ class Pool:
         rows.extend([rows[0]] * (p - len(rows)))
         pad_slots.extend([pad_slots[0]] * (p - len(pad_slots)))
         if isinstance(self.cache, BankRow):
-            view = self.cache
-            fn = _cached(
-                ("bank_scatter_multi_jit", p),
-                lambda: jax.jit(_bank_multi_scatter_impl, donate_argnums=(0,)))
-            view.bank.tree = fn(view.bank.tree, tuple(rows),
-                                np.int32(view.index), tuple(pad_slots))
+            self._bank_write(("bank_scatter_multi_jit", p),
+                             _bank_multi_scatter_impl, tuple(rows),
+                             tuple(pad_slots))
         else:
             fn = _cached(
                 ("scatter_multi_jit", self.cfg, self.max_seq_len, p),

@@ -174,7 +174,11 @@ class TestBatchedByteIdentity:
                                  batch_replicas=True,
                                  batch_layout="shard_map")
         assert shard_blob == vmap_blob
-        assert fleet.last_engine_stats.batched_decode_calls > 0
+        st = fleet.last_engine_stats
+        assert st.batched_decode_calls > 0
+        # the fallback is counted, never silent
+        assert st.vmap_fallbacks == st.batched_decode_calls
+        assert st.shard_map_calls == 0
 
     @pytest.mark.slow
     def test_shard_map_multi_device_identical(self):

@@ -135,7 +135,8 @@ class TestSingleReplicaEquivalence:
                  fleet.measured_energy_j()["solo"])
 
     def test_engine_builds_from_spec(self, setup):
-        eng = ServingEngine.from_spec(_rspec("eng"), params=setup[ARCH])
+        eng = ServingEngine.from_spec(_rspec("eng"), emodel=EnergyModel(H200_SXM),
+                                      params=setup[ARCH])
         assert eng.max_batch == 2 and eng.max_seq_len == 64
         req = eng.submit(np.arange(1, 9, dtype=np.int32), max_new_tokens=3)
         eng.run_to_completion()

@@ -42,7 +42,7 @@ class TestDecodeAttn:
         k = jax.random.normal(jax.random.fold_in(key, 1), (b, l, kv, dk), jnp.float32)
         v = jax.random.normal(jax.random.fold_in(key, 2), (b, l, kv, dv), jnp.float32)
         vl = jax.random.randint(jax.random.fold_in(key, 3), (b,), 1, l + 1)
-        out = decode_attention(q, k, v, vl, scale=0.2, block_k=blk)
+        out = decode_attention(q, k, v, vl, scale=0.2, block_k=blk, interpret=True)
         ref = decode_attention_ref(q, k, v, vl, scale=0.2)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL[jnp.float32])
 
@@ -54,7 +54,7 @@ class TestDecodeAttn:
         k = jax.random.normal(jax.random.fold_in(key, 1), (b, l, kv, d)).astype(dtype)
         v = jax.random.normal(jax.random.fold_in(key, 2), (b, l, kv, d)).astype(dtype)
         vl = jnp.array([l, l // 2], jnp.int32)
-        out = decode_attention(q, k, v, vl, scale=0.18, block_k=64)
+        out = decode_attention(q, k, v, vl, scale=0.18, block_k=64, interpret=True)
         ref = decode_attention_ref(
             q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32), vl, scale=0.18
         )
@@ -69,7 +69,7 @@ class TestDecodeAttn:
         k = jax.random.normal(jax.random.fold_in(key, 1), (b, l, kv, d))
         v = jax.random.normal(jax.random.fold_in(key, 2), (b, l, kv, d))
         vl = jnp.array([100, 37], jnp.int32)
-        out = gqa_decode_attention(q, k, v, vl, scale=0.25, block_k=32)
+        out = gqa_decode_attention(q, k, v, vl, scale=0.25, block_k=32, interpret=True)
         ref = decode_attention_ref(q, k, v, vl, scale=0.25)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=5e-5, atol=5e-5)
 
@@ -80,7 +80,7 @@ class TestDecodeAttn:
         k = jax.random.normal(jax.random.fold_in(key, 1), (b, l, kv, d))
         v = jax.random.normal(jax.random.fold_in(key, 2), (b, l, kv, d))
         vl = jnp.array([1], jnp.int32)
-        out = decode_attention(q, k, v, vl, scale=1.0, block_k=32)
+        out = decode_attention(q, k, v, vl, scale=1.0, block_k=32, interpret=True)
         np.testing.assert_allclose(np.asarray(out)[0], np.asarray(v)[0, 0, 0][None].repeat(2, 0), rtol=1e-5)
 
 
@@ -114,7 +114,7 @@ class TestPagedDecodeAttn:
         # valid length lands inside the last live block
         vl = (valid_blocks - 1) * bs + jax.random.randint(
             jax.random.fold_in(key, 5), (b,), 1, bs + 1)
-        out = paged_decode_attention(q, kp, vp, tables, vl, scale=0.2)
+        out = paged_decode_attention(q, kp, vp, tables, vl, scale=0.2, interpret=True)
         ref = paged_decode_attention_ref(q, kp, vp, tables, vl, scale=0.2)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL[jnp.float32])
 
@@ -130,10 +130,10 @@ class TestPagedDecodeAttn:
         tables = _random_tables(jax.random.fold_in(key, 3), b, nb, n_pages,
                                 np.array([4, 3]))
         vl = jnp.array([60, 41], jnp.int32)
-        out = paged_decode_attention(q, kp, vp, tables, vl, scale=0.18)
+        out = paged_decode_attention(q, kp, vp, tables, vl, scale=0.18, interpret=True)
         k_dense = kp[tables].reshape(b, nb * bs, kv, d)
         v_dense = vp[tables].reshape(b, nb * bs, kv, d)
-        ref = decode_attention(q, k_dense, v_dense, vl, scale=0.18, block_k=bs)
+        ref = decode_attention(q, k_dense, v_dense, vl, scale=0.18, block_k=bs, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=5e-5, atol=5e-5)
 
     def test_wrapper_accepts_query_seq_axis(self):
@@ -146,7 +146,7 @@ class TestPagedDecodeAttn:
         tables = _random_tables(jax.random.fold_in(key, 3), b, nb, n_pages,
                                 np.array([2, 1]))
         vl = jnp.array([12, 5], jnp.int32)
-        out = gqa_paged_decode_attention(q, kp, vp, tables, vl, scale=0.25)
+        out = gqa_paged_decode_attention(q, kp, vp, tables, vl, scale=0.25, interpret=True)
         assert out.shape == (b, 1, h, d)
         ref = paged_decode_attention_ref(q[:, 0], kp, vp, tables, vl, scale=0.25)
         np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(ref),
@@ -180,7 +180,7 @@ class TestMLADecode:
         ckv = jax.random.normal(jax.random.fold_in(key, 2), (b, l, rank))
         kr = jax.random.normal(jax.random.fold_in(key, 3), (b, l, rope))
         vl = jax.random.randint(jax.random.fold_in(key, 4), (b,), 1, l + 1)
-        out = mla_latent_decode(ql, qr, ckv, kr, vl, scale=0.12, block_l=blk)
+        out = mla_latent_decode(ql, qr, ckv, kr, vl, scale=0.12, block_l=blk, interpret=True)
         ref = mla_latent_decode_ref(ql, qr, ckv, kr, vl, scale=0.12)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=5e-5, atol=5e-5)
 
@@ -207,8 +207,7 @@ class TestMLADecode:
         ref = _attend_absorbed(p, q_nope, q_rope, ckv, kr, mask, cfg, jnp.float32)[:, 0]
         out = mla_fused_decode(
             p["w_uk"], p["w_uv"], p["w_o"], q_nope[:, 0], q_rope[:, 0],
-            ckv, kr, vl, scale=_mla_scale(cfg), block_l=16,
-        )
+            ckv, kr, vl, scale=_mla_scale(cfg), block_l=16, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
 
 
@@ -229,7 +228,7 @@ class TestPagedMLADecode:
         tables = _random_tables(jax.random.fold_in(key, 5), b, nb, n_pages, valid_blocks)
         vl = (valid_blocks - 1) * bs + jax.random.randint(
             jax.random.fold_in(key, 6), (b,), 1, bs + 1)
-        out = mla_paged_latent_decode(ql, qr, cp, krp, tables, vl, scale=0.12)
+        out = mla_paged_latent_decode(ql, qr, cp, krp, tables, vl, scale=0.12, interpret=True)
         ref = mla_paged_latent_decode_ref(ql, qr, cp, krp, tables, vl, scale=0.12)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=5e-5, atol=5e-5)
 
@@ -257,12 +256,12 @@ class TestPagedMLADecode:
         vl = jnp.array([22, 11], jnp.int32)
         out = mla_paged_fused_decode(
             p["w_uk"], p["w_uv"], p["w_o"], q_nope, q_rope,
-            cp, krp, tables, vl, scale=_mla_scale(cfg))
+            cp, krp, tables, vl, scale=_mla_scale(cfg), interpret=True)
         ckv = cp[tables].reshape(B, nb * bs, 16)
         kr = krp[tables].reshape(B, nb * bs, 4)
         ref = mla_fused_decode(
             p["w_uk"], p["w_uv"], p["w_o"], q_nope, q_rope,
-            ckv, kr, vl, scale=_mla_scale(cfg), block_l=bs)
+            ckv, kr, vl, scale=_mla_scale(cfg), block_l=bs, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
 
 
@@ -279,7 +278,7 @@ class TestSSD:
         a = -jnp.exp(jnp.linspace(-2, 0.5, h))
         bm = jax.random.normal(jax.random.fold_in(key, 2), (b, s, n)) * 0.3
         cm = jax.random.normal(jax.random.fold_in(key, 3), (b, s, n)) * 0.3
-        y, fs = ssd_prefill(x, dt, a, bm, cm, q_chunk=q, head_block=hb)
+        y, fs = ssd_prefill(x, dt, a, bm, cm, q_chunk=q, head_block=hb, interpret=True)
         yr, fsr = ssd_scan_ref(x, dt, a, bm, cm)
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=2e-4, atol=2e-4)
         np.testing.assert_allclose(np.asarray(fs), np.asarray(fsr), rtol=2e-4, atol=2e-4)
@@ -292,7 +291,7 @@ class TestSSD:
         a = -jnp.exp(jnp.linspace(-1, 0.3, h))
         bm = jax.random.normal(jax.random.fold_in(key, 2), (b, s, n)) * 0.3
         cm = jax.random.normal(jax.random.fold_in(key, 3), (b, s, n)) * 0.3
-        y, fs = ssd_prefill(x, dt, a, bm, cm, q_chunk=16, head_block=4)
+        y, fs = ssd_prefill(x, dt, a, bm, cm, q_chunk=16, head_block=4, interpret=True)
         yr, fsr = ssd_scan_ref(x, dt, a, bm, cm)
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=2e-4, atol=2e-4)
         np.testing.assert_allclose(np.asarray(fs), np.asarray(fsr), rtol=2e-4, atol=2e-4)
@@ -307,7 +306,7 @@ class TestSSD:
         a = -jnp.exp(jnp.linspace(-2, 0.5, h))
         bm = jax.random.normal(jax.random.fold_in(key, 2), (b, s, n)) * 0.3
         cm = jax.random.normal(jax.random.fold_in(key, 3), (b, s, n)) * 0.3
-        y1, f1 = ssd_prefill(x, dt, a, bm, cm, q_chunk=8, head_block=2)
+        y1, f1 = ssd_prefill(x, dt, a, bm, cm, q_chunk=8, head_block=2, interpret=True)
         y2, f2 = ssd_chunked(x, dt, a, bm[:, :, None], cm[:, :, None], 8)
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=2e-4, atol=2e-4)
         np.testing.assert_allclose(np.asarray(f1), np.asarray(f2), rtol=2e-4, atol=2e-4)
@@ -328,7 +327,7 @@ class TestGDN:
         vv = jax.random.normal(jax.random.fold_in(key, 2), (b, s, h, k)) * 0.5
         beta = jax.nn.sigmoid(jax.random.normal(jax.random.fold_in(key, 3), (b, s, h)))
         alpha = jax.nn.sigmoid(jax.random.normal(jax.random.fold_in(key, 4), (b, s, h)) + 2)
-        y, fs = gdn_prefill(qv, kv, vv, beta, alpha, q_chunk=q)
+        y, fs = gdn_prefill(qv, kv, vv, beta, alpha, q_chunk=q, interpret=True)
         yr, fsr = gdn_scan_ref(qv, kv, vv, beta, alpha)
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=2e-4, atol=2e-4)
         np.testing.assert_allclose(np.asarray(fs), np.asarray(fsr), rtol=2e-4, atol=2e-4)
@@ -343,6 +342,6 @@ class TestGDN:
         k = eye
         v = jax.random.normal(jax.random.PRNGKey(0), (b, s, h, kd))
         ones = jnp.ones((b, s, h))
-        y, fs = gdn_prefill(q, k, v, ones, ones, q_chunk=4)
+        y, fs = gdn_prefill(q, k, v, ones, ones, q_chunk=4, interpret=True)
         # final state: S[k_i] row = v_i
         np.testing.assert_allclose(np.asarray(fs[0, 0]), np.asarray(v[0, :, 0]), rtol=1e-5, atol=1e-5)
