@@ -81,6 +81,7 @@ from repro.serving import (
     ReplicaSpec,
     clear_program_caches,
 )
+from repro.serving import spans
 from repro.serving.pool import release_request
 
 ARCH_MAIN = "gemma-2b"
@@ -229,12 +230,32 @@ def replay(trace, **engine_opts):
     return metrics, stream.digest(fleet), wall_s
 
 
+def fused_walls(records) -> dict:
+    """Wall seconds of each fused decode group, from the span recorder's
+    ``decode.fused`` records, by padded group size as a string: size ->
+    [calls, seconds]. A group's wall is its dispatch and its members'
+    tokens reaching the host: the span less its ``decode.account``
+    children."""
+    account: dict = {}
+    for name, t0, t1, parent, _ in records:
+        if name == "decode.account" and parent >= 0:
+            account[parent] = account.get(parent, 0) + t1 - t0
+    out: dict = {}
+    for i, (name, t0, t1, _, size) in enumerate(records):
+        if name == "decode.fused":
+            ent = out.setdefault(str(size), [0, 0.0])
+            ent[0] += 1
+            ent[1] += (t1 - t0 - account.get(i, 0)) / 1e9
+    return out
+
+
 def dispatch_curve(smoke: bool):
     """Measured wall seconds inside fused decode dispatches vs group size,
-    batched vs tuple program, on the aligned trace (full fused coverage).
-    ``clear_program_caches()`` between points so every point pays its own
-    compiles — the curve is (compile + dispatch) per fused call, the cost a
-    replay actually sees the first time it meets a group size."""
+    batched vs tuple program, on the aligned trace (full fused coverage),
+    read from the span recorder. ``clear_program_caches()`` between points
+    so every point pays its own compiles — the curve is (compile +
+    dispatch) per fused call, the cost a replay actually sees the first
+    time it meets a group size."""
     sweep = (4, 8, 32) if smoke else (4, 8, 16, 32, 64)
     n = 1_500 if smoke else 20_000
     trace, _ = aligned_trace(n)
@@ -243,17 +264,23 @@ def dispatch_curve(smoke: bool):
         for mode, flag in (("batched", True), ("tuple", False)):
             clear_program_caches()
             fleet = make_fleet()
-            fleet.run_trace(trace, max_steps=1_000_000_000, engine_opts={
-                "fusion_quantum_s": QUANTUM_S, "max_fused_group": g,
-                "batch_replicas": flag, "time_dispatch": True})
-            st = fleet.last_engine_stats
-            calls = sum(int(v[0]) for v in st.fused_decode_wall.values())
-            secs = sum(v[1] for v in st.fused_decode_wall.values())
+            spans.clear()
+            spans.enable()
+            try:
+                fleet.run_trace(trace, max_steps=1_000_000_000, engine_opts={
+                    "fusion_quantum_s": QUANTUM_S, "max_fused_group": g,
+                    "batch_replicas": flag})
+            finally:
+                spans.disable()
+            by_size = fused_walls(spans.records())
+            spans.clear()
+            calls = sum(v[0] for v in by_size.values())
+            secs = sum(v[1] for v in by_size.values())
             curve.setdefault(str(g), {})[mode] = {
                 "fused_calls": calls,
                 "dispatch_wall_s": secs,
                 "us_per_fused_call": 1e6 * secs / max(calls, 1),
-                "by_size": st.fused_decode_wall,
+                "by_size": by_size,
             }
     clear_program_caches()
     return curve

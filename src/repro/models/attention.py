@@ -89,27 +89,29 @@ def self_attention_prefill(
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)[None, :]
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn"):
+        q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
 
-    window = 0 if is_global else cfg.sliding_window
-    ctx = attention_prefill_auto(
-        q, k, v,
-        scale=_attn_scale(cfg),
-        causal=True,
-        window=window,
-        softcap=cfg.attn_softcap,
-    ).astype(x.dtype)
-    out = jnp.einsum("bshk,hkd->bsd", ctx, params["wo"])
+        window = 0 if is_global else cfg.sliding_window
+        ctx = attention_prefill_auto(
+            q, k, v,
+            scale=_attn_scale(cfg),
+            causal=True,
+            window=window,
+            softcap=cfg.attn_softcap,
+        ).astype(x.dtype)
+        out = jnp.einsum("bshk,hkd->bsd", ctx, params["wo"])
 
     if cache is not None:
-        cache = {
-            "k": jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)),
-        }
+        with jax.named_scope("kv_write"):
+            cache = {
+                "k": jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)),
+                "v": jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)),
+            }
     return out, cache
 
 
@@ -137,32 +139,36 @@ def self_attention_prefill_suffix(
     if b != 1:
         raise ValueError(f"suffix prefill is batch-1 (got batch={b})")
     positions = prefix_len[:, None] + jnp.arange(s)[None, :]   # (1, S)
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn"):
+        q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
 
     off = prefix_len[0]
-    k_buf = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, off, 0, 0))
-    v_buf = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, off, 0, 0))
+    with jax.named_scope("kv_write"):
+        k_buf = jax.lax.dynamic_update_slice(
+            cache["k"], k.astype(cache["k"].dtype), (0, off, 0, 0))
+        v_buf = jax.lax.dynamic_update_slice(
+            cache["v"], v.astype(cache["v"].dtype), (0, off, 0, 0))
 
-    l_max = k_buf.shape[1]
-    kpos = jnp.arange(l_max)[None, None, :]                    # (1, 1, L)
-    valid = kpos <= positions[:, :, None]                      # (1, S, L)
-    if not is_global and cfg.sliding_window > 0:
-        valid &= (positions[:, :, None] - kpos) < cfg.sliding_window
-    mask = valid[:, None, None, :, :]                          # (1,1,1,S,L)
+    with jax.named_scope("attn"):
+        l_max = k_buf.shape[1]
+        kpos = jnp.arange(l_max)[None, None, :]                    # (1, 1, L)
+        valid = kpos <= positions[:, :, None]                      # (1, S, L)
+        if not is_global and cfg.sliding_window > 0:
+            valid &= (positions[:, :, None] - kpos) < cfg.sliding_window
+        mask = valid[:, None, None, :, :]                          # (1,1,1,S,L)
 
-    scores = _grouped_scores(
-        q, k_buf.astype(x.dtype), _attn_scale(cfg), cfg.attn_softcap)
-    ctx = _attend(scores, v_buf.astype(x.dtype), mask, x.dtype)
-    out = jnp.einsum("bshk,hkd->bsd", ctx, params["wo"])
+        scores = _grouped_scores(
+            q, k_buf.astype(x.dtype), _attn_scale(cfg), cfg.attn_softcap)
+        ctx = _attend(scores, v_buf.astype(x.dtype), mask, x.dtype)
+        out = jnp.einsum("bshk,hkd->bsd", ctx, params["wo"])
     return out, {"k": k_buf, "v": v_buf}
 
 
+@jax.named_scope("kv_write")
 def _paged_token_write(
     pages: jax.Array,         # (P, bs, ...) physical pages; page 0 reserved/null
     new: jax.Array,           # (B, 1, ...) the new token's row per request
@@ -192,6 +198,7 @@ def _gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
     return pages[block_tables].reshape(b, nb * bs, *pages.shape[2:])
 
 
+@jax.named_scope("kv_write")
 def _write_at_lengths(buf: jax.Array, new: jax.Array, lengths: jax.Array) -> jax.Array:
     """Per-example cache write at ragged positions: buf (B,L,...), new (B,1,...).
 
@@ -206,6 +213,7 @@ def _write_at_lengths(buf: jax.Array, new: jax.Array, lengths: jax.Array) -> jax
     return jnp.where(mask, new.astype(buf.dtype), buf)
 
 
+@jax.named_scope("attn")
 def _decode_qkv(params, x, lengths, cfg):
     """Shared decode-step projections: rope'd q and new-token k/v rows."""
     positions = lengths[:, None]     # new token's position
@@ -217,6 +225,7 @@ def _decode_qkv(params, x, lengths, cfg):
     return q, k_new, v_new
 
 
+@jax.named_scope("attn")
 def _decode_attend(params, q, k_buf, v_buf, lengths, cfg, is_global, out_dtype):
     """Masked grouped attention of one query row over a contiguous buffer —
     the buffer may be a dense slot row or a gathered page view; the mask is

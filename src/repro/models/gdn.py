@@ -129,7 +129,8 @@ def gdn_decode(
     cfg,
 ) -> Tuple[jax.Array, Dict]:
     q, k, v, beta, alpha = _qkv_gates(params, x, cfg)
-    y, new_state = gdn_step(q[:, 0], k[:, 0], v[:, 0], beta[:, 0], alpha[:, 0], cache["gdn"])
+    with jax.named_scope("kv_write"):      # the recurrent state's update
+        y, new_state = gdn_step(q[:, 0], k[:, 0], v[:, 0], beta[:, 0], alpha[:, 0], cache["gdn"])
     z_gate = jnp.einsum("bsd,dhk->bshk", x, params["w_gate"])
     out = _finish(params, y[:, None], z_gate, x, cfg)
     return out, {"gdn": new_state}

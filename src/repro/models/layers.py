@@ -67,6 +67,7 @@ def init_mlp(key, d_model: int, d_ff: int, mlp_type: str, dtype) -> Dict:
     return params
 
 
+@jax.named_scope("mlp")
 def mlp(params, x, mlp_type: str):
     up = x @ params["w_up"]
     if mlp_type == "swiglu":

@@ -146,18 +146,20 @@ def mla_prefill(
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)[None, :]
-    q_nope, q_rope = _queries(params, x, positions, cfg)
-    ckv, kr = _latents(params, x, positions, cfg)
-    if absorb:
-        out = _attend_absorbed_blocked(params, q_nope, q_rope, ckv, kr, cfg, x.dtype)
-    else:
-        mask = (jnp.arange(s)[:, None] >= jnp.arange(s)[None, :])[None, None]
-        out = _attend_naive(params, q_nope, q_rope, ckv, kr, mask, cfg, x.dtype)
+    with jax.named_scope("attn"):
+        q_nope, q_rope = _queries(params, x, positions, cfg)
+        ckv, kr = _latents(params, x, positions, cfg)
+        if absorb:
+            out = _attend_absorbed_blocked(params, q_nope, q_rope, ckv, kr, cfg, x.dtype)
+        else:
+            mask = (jnp.arange(s)[:, None] >= jnp.arange(s)[None, :])[None, None]
+            out = _attend_naive(params, q_nope, q_rope, ckv, kr, mask, cfg, x.dtype)
     if cache is not None:
-        cache = {
-            "ckv": jax.lax.dynamic_update_slice(cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, 0, 0)),
-            "kr": jax.lax.dynamic_update_slice(cache["kr"], kr.astype(cache["kr"].dtype), (0, 0, 0)),
-        }
+        with jax.named_scope("kv_write"):
+            cache = {
+                "ckv": jax.lax.dynamic_update_slice(cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, 0, 0)),
+                "kr": jax.lax.dynamic_update_slice(cache["kr"], kr.astype(cache["kr"].dtype), (0, 0, 0)),
+            }
     return out, cache
 
 
@@ -171,18 +173,20 @@ def mla_decode(
     absorb: bool,
 ) -> Tuple[jax.Array, Dict]:
     positions = lengths[:, None]
-    q_nope, q_rope = _queries(params, x, positions, cfg)
-    ckv_new, kr_new = _latents(params, x, positions, cfg)
+    with jax.named_scope("attn"):
+        q_nope, q_rope = _queries(params, x, positions, cfg)
+        ckv_new, kr_new = _latents(params, x, positions, cfg)
 
     ckv_buf = _write_at_lengths(cache["ckv"], ckv_new, lengths)
     kr_buf = _write_at_lengths(cache["kr"], kr_new, lengths)
 
-    l_max = ckv_buf.shape[1]
-    mask = (jnp.arange(l_max)[None, :] <= lengths[:, None])[:, None, None, :]
-    attend = _attend_absorbed if absorb else _attend_naive
-    out = attend(
-        params, q_nope, q_rope, ckv_buf.astype(x.dtype), kr_buf.astype(x.dtype), mask, cfg, x.dtype
-    )
+    with jax.named_scope("attn"):
+        l_max = ckv_buf.shape[1]
+        mask = (jnp.arange(l_max)[None, :] <= lengths[:, None])[:, None, None, :]
+        attend = _attend_absorbed if absorb else _attend_naive
+        out = attend(
+            params, q_nope, q_rope, ckv_buf.astype(x.dtype), kr_buf.astype(x.dtype), mask, cfg, x.dtype
+        )
     return out, {"ckv": ckv_buf, "kr": kr_buf}
 
 
@@ -204,18 +208,20 @@ def mla_decode_paged(
     traffic meter makes visible per block. TPU kernel counterpart:
     ``kernels.mla_decode.mla_paged_fused_decode``."""
     positions = lengths[:, None]
-    q_nope, q_rope = _queries(params, x, positions, cfg)
-    ckv_new, kr_new = _latents(params, x, positions, cfg)
+    with jax.named_scope("attn"):
+        q_nope, q_rope = _queries(params, x, positions, cfg)
+        ckv_new, kr_new = _latents(params, x, positions, cfg)
 
     ckv_pages = _paged_token_write(cache["ckv"], ckv_new, block_tables, lengths, active)
     kr_pages = _paged_token_write(cache["kr"], kr_new, block_tables, lengths, active)
     ckv_buf = _gather_pages(ckv_pages, block_tables)
     kr_buf = _gather_pages(kr_pages, block_tables)
 
-    l_max = ckv_buf.shape[1]
-    mask = (jnp.arange(l_max)[None, :] <= lengths[:, None])[:, None, None, :]
-    attend = _attend_absorbed if absorb else _attend_naive
-    out = attend(
-        params, q_nope, q_rope, ckv_buf.astype(x.dtype), kr_buf.astype(x.dtype), mask, cfg, x.dtype
-    )
+    with jax.named_scope("attn"):
+        l_max = ckv_buf.shape[1]
+        mask = (jnp.arange(l_max)[None, :] <= lengths[:, None])[:, None, None, :]
+        attend = _attend_absorbed if absorb else _attend_naive
+        out = attend(
+            params, q_nope, q_rope, ckv_buf.astype(x.dtype), kr_buf.astype(x.dtype), mask, cfg, x.dtype
+        )
     return out, {"ckv": ckv_pages, "kr": kr_pages}

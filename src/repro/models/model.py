@@ -449,6 +449,7 @@ def forward(
     return rmsnorm(params["final_norm"], x, cfg.rms_eps)
 
 
+@jax.named_scope("lm_head")
 def logits(params: Dict, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:
     return unembed(params["embed"], hidden, cfg.final_softcap)
 

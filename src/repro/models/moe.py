@@ -41,6 +41,7 @@ def _capacity(cfg, n_tokens: int) -> int:
     return max(8, int(np.ceil(per * cfg.moe_capacity_factor)))
 
 
+@jax.named_scope("mlp")
 def moe_mlp(params: Dict, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
     """x: (B, S, d) -> (out (B, S, d), aux load-balance loss scalar)."""
     b, s, d = x.shape

@@ -212,17 +212,19 @@ def ssm_decode(
     proj = x @ params["w_in"]
     z, xs, b, c, dtp = _split_proj(cfg, proj)
     conv_in = jnp.concatenate([xs, b, c], axis=-1)          # (B,1,conv_dim)
-    conv_out, conv_state = _causal_conv(
-        conv_in, params["conv_w"], params["conv_b"], cache["conv"]
-    )
+    with jax.named_scope("kv_write"):      # the conv window's update
+        conv_out, conv_state = _causal_conv(
+            conv_in, params["conv_w"], params["conv_b"], cache["conv"]
+        )
     xs, b, c = jnp.split(conv_out[:, 0], [d_inner, d_inner + g * n], axis=-1)
 
     dtv = jax.nn.softplus(dtp[:, 0].astype(jnp.float32) + params["dt_bias"])
     a = -jnp.exp(params["a_log"])
-    y, new_state = ssd_step(
-        xs.reshape(bsz, heads, p), dtv, a, b.reshape(bsz, g, n), c.reshape(bsz, g, n),
-        cache["ssm"],
-    )
+    with jax.named_scope("kv_write"):      # the recurrent state's update
+        y, new_state = ssd_step(
+            xs.reshape(bsz, heads, p), dtv, a, b.reshape(bsz, g, n), c.reshape(bsz, g, n),
+            cache["ssm"],
+        )
     y = y + params["d_skip"][None, :, None] * xs.reshape(bsz, heads, p).astype(jnp.float32)
     y = y.reshape(bsz, 1, d_inner).astype(x.dtype)
     y = rmsnorm(params["norm"], y * jax.nn.silu(z), cfg.rms_eps)

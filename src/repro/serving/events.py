@@ -95,7 +95,6 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-import time
 from collections import OrderedDict
 from typing import (
     Any, Callable, Deque, Dict, List, Optional, Set, Tuple, TYPE_CHECKING,
@@ -110,6 +109,7 @@ from repro.serving.pool import (
     BankRow, CacheBank, Pool, Request, row_on_device, observe_latencies,
     requeue_front,
 )
+from repro.serving.spans import span
 
 BATCH_LAYOUTS = ("vmap", "shard_map")
 
@@ -232,12 +232,6 @@ class EngineStats:
                                        # banks / dense trees, stacked in-jit
     fused_traces: int = 0              # fused jit programs built (LRU inserts)
     pad_waste: int = 0                 # inert pad slots across fused calls
-    # measured wall seconds inside fused decode dispatches, keyed by the
-    # pow2-padded group size as a string: size -> [calls, seconds]. Only
-    # populated with ``time_dispatch=True`` (blocking on each dispatch
-    # perturbs overlap, so the default replay never pays it)
-    fused_decode_wall: Dict[str, List[float]] = dataclasses.field(
-        default_factory=dict)
     pool_jit_dispatches: int = 0       # serial dispatches made by the pools
                                        # (prefill + scatter + serial decode)
     # prefix-sharing counters (pool lifetime, summed over decode pools at
@@ -296,7 +290,6 @@ class EventDrivenFleet:
                  fused_cache_cap: int = 64,
                  batch_replicas: bool = True,
                  batch_layout: str = "vmap",
-                 time_dispatch: bool = False,
                  on_finish: Optional[Callable[[Request], None]] = None):
         if not fleet.virtual:
             raise ValueError("the event engine needs VirtualClock replicas")
@@ -327,7 +320,6 @@ class EventDrivenFleet:
         # replicated weights assembled from the members' per-device copies,
         # keyed (params_token, devices)
         self._mesh_params: Dict[Tuple[Any, ...], Any] = {}
-        self.time_dispatch = bool(time_dispatch)
         self.on_finish = on_finish
         self.stats = EngineStats()
         self._heap: List[Tuple[float, int, int, str, Any]] = []
@@ -966,25 +958,24 @@ class EventDrivenFleet:
         for chunk in self._decode_chunks(pools):
             chunk_pools = [pools[j] for j in chunk]
             chunk_pres = [pres[j] for j in chunk]
-            t0 = time.perf_counter() if self.time_dispatch else 0.0
-            if self._mesh and chunk_pools[0].device is not None:
-                outs, p2 = self._decode_chunk_mesh(sig, chunk_pools,
-                                                   chunk_pres)
-            elif self.batch_replicas:
-                outs, p2 = self._decode_chunk_batched(sig, chunk_pools,
+            mesh = self._mesh and chunk_pools[0].device is not None
+            # the group's padded size: a mesh group takes no padding
+            p2 = len(chunk) if mesh else self._pow2(len(chunk))
+            # one fused group: its dispatch, then each member's tokens to
+            # the host and accounting (``decode.sync``/``decode.account``
+            # inside it)
+            with span("decode.fused", p2):
+                if mesh:
+                    outs = self._decode_chunk_mesh(sig, chunk_pools, chunk_pres)
+                elif self.batch_replicas:
+                    outs = self._decode_chunk_batched(sig, chunk_pools,
                                                       chunk_pres)
-            else:
-                outs, p2 = self._decode_chunk_tuple(sig, chunk_pools,
-                                                    chunk_pres)
-            if self.time_dispatch:
-                jax.block_until_ready(outs)
-                ent = st.fused_decode_wall.setdefault(str(p2), [0, 0.0])
-                ent[0] += 1
-                ent[1] += time.perf_counter() - t0
-            st.fused_decode_calls += 1
-            st.pad_waste += p2 - len(chunk_pools)
-            for j, pre, out in zip(chunk, chunk_pres, outs):
-                finished[reps[j].name] = pools[j]._decode_finish(pre, *out)
+                else:
+                    outs = self._decode_chunk_tuple(sig, chunk_pools, chunk_pres)
+                st.fused_decode_calls += 1
+                st.pad_waste += p2 - len(chunk_pools)
+                for j, pre, out in zip(chunk, chunk_pres, outs):
+                    finished[reps[j].name] = pools[j]._decode_finish(pre, *out)
         return finished
 
     def _decode_chunks(self, pools: List[Pool]) -> List[List[int]]:
@@ -1008,7 +999,7 @@ class EventDrivenFleet:
         return chunks
 
     def _decode_chunk_tuple(self, sig, pools: List[Pool],
-                            pres: List[dict]) -> Tuple[List[Any], int]:
+                            pres: List[dict]) -> List[Any]:
         """The PR-7 fused program: K traced sub-calls over a tuple of
         per-pool argument tuples."""
         k = len(pools)
@@ -1030,7 +1021,7 @@ class EventDrivenFleet:
 
         fn = self._fused_fn(("decode", sig, p2), build)
         outs = fn(pool0.params, tuple(args_list))
-        return list(outs[:k]), p2
+        return list(outs[:k])
 
     def _bank_coherent(self, pools: List[Pool], p2: int) -> Optional[CacheBank]:
         """The chunk's persistent stacked bank, if every member still views
@@ -1064,7 +1055,7 @@ class EventDrivenFleet:
         return bank, idx
 
     def _decode_chunk_batched(self, sig, pools: List[Pool],
-                              pres: List[dict]) -> Tuple[List[Any], int]:
+                              pres: List[dict]) -> List[Any]:
         """ONE batched program per chunk: dense decode args stack along a
         leading replica axis (pow2-padded with repeats of member 0) and run
         through ``vmap_replicas`` (or ``shard_map_replicas``).
@@ -1205,10 +1196,10 @@ class EventDrivenFleet:
         next_np = np.asarray(next_tok)
         len_np = np.asarray(new_lengths)
         return [(next_np[j], BankRow(bank, j), len_np[j])
-                for j in range(k)], p2
+                for j in range(k)]
 
     def _decode_chunk_mesh(self, sig, pools: List[Pool],
-                           pres: List[dict]) -> Tuple[List[Any], int]:
+                           pres: List[dict]) -> List[Any]:
         """ONE shard_map program for device-bound pools on distinct
         devices: the mesh is exactly their devices, and row j of the
         stacked bank lives on pool j's device. No padding: the mesh takes
@@ -1251,7 +1242,7 @@ class EventDrivenFleet:
         next_np = np.asarray(next_tok)
         len_np = np.asarray(new_lengths)
         return [(next_np[j], BankRow(bank, j), len_np[j])
-                for j in range(k)], k
+                for j in range(k)]
 
     @staticmethod
     def _mesh_row(pool: Pool):

@@ -74,6 +74,7 @@ from repro.serving.pool import (
     requeue_front,
 )
 from repro.serving.router import JoinShortestQueue, Router, make_router
+from repro.serving.spans import span
 from repro.serving.spec import FleetSpec, ReplicaSpec
 
 
@@ -117,56 +118,57 @@ class Scheduler:
         ``accrue=False`` spends existing credit without banking more (the
         engine calls extra ticks at arrival events; credit still accrues
         once per decode step, the barrier's cadence)."""
-        if not waiting:
-            self._credit = 0.0
-            return []
-        if gate is None:
-            gate = decode_pool.can_admit
-        if admit is None:
-            def admit(req: Request) -> None:
-                # prefix sharing: pin any shared-prefix hit on the decode
-                # pool first, prefill only the un-shared suffix (gathered
-                # from the donor's pages), and place with the shared table
-                # entries. With sharing off the hit is None and this is the
-                # legacy handoff, byte for byte.
-                hit = decode_pool.prefix_acquire(req)
-                first, cache1 = prefill_pool.prefill_request(
-                    req, shared=hit, donor=decode_pool)
-                decode_pool.place(
-                    req, cache1, first, len(req.prompt), shared=hit,
-                    # with split pool clocks the first token exists when the
-                    # PREFILL timeline produced it; on a shared clock this
-                    # is exactly the legacy stamp
-                    first_token_s=(prefill_pool.clock()
-                                   if prefill_pool.virtual else None))
-        validated_head = head_validator(waiting, decode_pool)
-        # fail fast even when admission is impossible this tick
-        head = validated_head()
-        if gate(head) and accrue:
-            # accrue only while admission is possible, capped at
-            # max(chunk, head need) — a full decode pool must not bank
-            # credit that later releases one giant prefill burst.
-            # can_admit is the continuous-batching gate: on a paged pool it
-            # asks the block allocator, not a fixed slot count.
-            self._credit = min(
-                self._credit + self.chunk_tokens,
-                max(float(self.chunk_tokens),
-                    float(decode_pool.prefill_cost_tokens(head))),
-            )
-        admitted: List[Request] = []
-        while waiting and gate(waiting[0]):
-            req = validated_head()
-            # charge the tokens prefill will actually compute — the suffix
-            # only, under a prefix hit (identical to len(prompt) otherwise)
-            need = decode_pool.prefill_cost_tokens(req)
-            if need > self._credit:
-                break
-            popleft(waiting)
-            self._credit -= need
-            admit(req)
-            self.migrations += 1
-            admitted.append(req)
-        return admitted
+        with span("admit"):
+            if not waiting:
+                self._credit = 0.0
+                return []
+            if gate is None:
+                gate = decode_pool.can_admit
+            if admit is None:
+                def admit(req: Request) -> None:
+                    # prefix sharing: pin any shared-prefix hit on the decode
+                    # pool first, prefill only the un-shared suffix (gathered
+                    # from the donor's pages), and place with the shared table
+                    # entries. With sharing off the hit is None and this is the
+                    # legacy handoff, byte for byte.
+                    hit = decode_pool.prefix_acquire(req)
+                    first, cache1 = prefill_pool.prefill_request(
+                        req, shared=hit, donor=decode_pool)
+                    decode_pool.place(
+                        req, cache1, first, len(req.prompt), shared=hit,
+                        # with split pool clocks the first token exists when the
+                        # PREFILL timeline produced it; on a shared clock this
+                        # is exactly the legacy stamp
+                        first_token_s=(prefill_pool.clock()
+                                       if prefill_pool.virtual else None))
+            validated_head = head_validator(waiting, decode_pool)
+            # fail fast even when admission is impossible this tick
+            head = validated_head()
+            if gate(head) and accrue:
+                # accrue only while admission is possible, capped at
+                # max(chunk, head need) — a full decode pool must not bank
+                # credit that later releases one giant prefill burst.
+                # can_admit is the continuous-batching gate: on a paged pool it
+                # asks the block allocator, not a fixed slot count.
+                self._credit = min(
+                    self._credit + self.chunk_tokens,
+                    max(float(self.chunk_tokens),
+                        float(decode_pool.prefill_cost_tokens(head))),
+                )
+            admitted: List[Request] = []
+            while waiting and gate(waiting[0]):
+                req = validated_head()
+                # charge the tokens prefill will actually compute — the suffix
+                # only, under a prefix hit (identical to len(prompt) otherwise)
+                need = decode_pool.prefill_cost_tokens(req)
+                if need > self._credit:
+                    break
+                popleft(waiting)
+                self._credit -= need
+                admit(req)
+                self.migrations += 1
+                admitted.append(req)
+            return admitted
 
 
 class Replica:
@@ -364,32 +366,35 @@ class Replica:
         decode step on one timeline (``sync_clocks`` after admission), the
         legacy colocated-device view. The event engine overlaps the two
         timelines instead — see ``repro.serving.events``."""
-        self._step_no += 1
-        self.sync_clocks()
-        if self.warming():
-            # inside the warm-up window: idle-floor watts accrue (the
-            # barrier samples this replica's pools) but nothing admits —
-            # queued work waits until the fleet marks the replica warm
-            return []
-        if self.controller is not None:
-            self.controller.tick(self.pools(), self._step_no)
-        admitted = self.scheduler.tick(self.waiting, self.prefill_pool, self.decode_pool)
-        for req in admitted:
-            self.admit_log.append((req.ledger.admitted_s, req.ledger.queue_s))
-        if self.controller is not None and admitted:
-            # admission changed decode occupancy: re-resolve so this step's
-            # tokens are priced at the true post-admission operating point
-            self.controller.tick(self.pools(), self._step_no)
-        # under split pool clocks the prefill timeline ran ahead: the
-        # barrier's decode step starts only after admission completes
-        self.sync_clocks()
-        finished = self.decode_pool.decode_once()
-        if self.controller is not None:
-            observe_latencies(self.controller, self.decode_pool, admitted, finished)
-        # preempted requests go back to the queue head: they are the oldest
-        # work in flight, and FIFO admission re-prefills them first
-        requeue_front(self.waiting, self.decode_pool.take_evicted())
-        return finished
+        with span("step"):
+            self._step_no += 1
+            self.sync_clocks()
+            if self.warming():
+                # inside the warm-up window: idle-floor watts accrue (the
+                # barrier samples this replica's pools) but nothing admits —
+                # queued work waits until the fleet marks the replica warm
+                return []
+            if self.controller is not None:
+                with span("controller"):
+                    self.controller.tick(self.pools(), self._step_no)
+            admitted = self.scheduler.tick(self.waiting, self.prefill_pool, self.decode_pool)
+            for req in admitted:
+                self.admit_log.append((req.ledger.admitted_s, req.ledger.queue_s))
+            if self.controller is not None and admitted:
+                # admission changed decode occupancy: re-resolve so this step's
+                # tokens are priced at the true post-admission operating point
+                with span("controller"):
+                    self.controller.tick(self.pools(), self._step_no)
+            # under split pool clocks the prefill timeline ran ahead: the
+            # barrier's decode step starts only after admission completes
+            self.sync_clocks()
+            finished = self.decode_pool.decode_once()
+            if self.controller is not None:
+                observe_latencies(self.controller, self.decode_pool, admitted, finished)
+            # preempted requests go back to the queue head: they are the oldest
+            # work in flight, and FIFO admission re-prefills them first
+            requeue_front(self.waiting, self.decode_pool.take_evicted())
+            return finished
 
     def busy(self) -> bool:
         return bool(self.waiting) or self.decode_pool.occupancy() > 0
@@ -886,7 +891,8 @@ class Fleet:
                     p.clock.advance_to(target)
                 r.sample_pools()
         else:
-            time.sleep(dt_s)
+            with span("replay.idle"):
+                time.sleep(dt_s)
 
     def _cross_idle_gap(self, gap_s: float):
         """Cross an all-idle stretch between arrivals. With an autoscaler

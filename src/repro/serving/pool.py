@@ -83,6 +83,7 @@ from repro.models import (
 from repro.models.config import ModelConfig
 from repro.serving.paged_cache import NULL_PAGE, BlockAllocator, TrafficCounter
 from repro.serving.prefix import PrefixHit, PrefixIndex, PrefixStats
+from repro.serving.spans import span
 
 # Attention paradigms whose KV rows depend only on their own prefix — the
 # precondition for sharing cached pages across requests. Recurrent/MoE-state
@@ -793,6 +794,7 @@ class Pool:
         return copy_page_impl
 
     @staticmethod
+    @jax.named_scope("sample")
     def _sample(logits, key, temperature):
         """Per-slot sampling: ``temperature`` is a (B,) vector; slots at 0
         take the argmax (bit-identical to the all-greedy seed path), the
@@ -1283,60 +1285,62 @@ class Pool:
         banked in the donor's ``prefix_stats.saved_*`` side-channel — never
         added to any energy total, so conservation is untouched.
         """
-        l = len(req.prompt)
-        work = l if shared is None else l - shared.prefix_tokens
-        self._in_phase_call = True
-        self._refresh_gauge()
-        t0 = self.clock()
-        req.ledger.mark_admitted(t0)
-        try:
-            if precomputed is None:
-                if shared is not None:
-                    dp = donor if donor is not None else self
-                    toks, true_len, _ = self.suffix_tokens(
-                        req, shared.prefix_tokens)
-                    logits, cache1 = dp.shared_prefill(
-                        self.params, toks, true_len, shared)
-                else:
-                    toks, true_len, bucket = self.prefill_tokens(req)
-                    logits, cache1 = self._jit_prefill(
-                        self.params, toks, true_len, bucket=bucket
-                    )
-                self.jit_dispatches += 1
-            else:
-                logits, cache1 = precomputed
-            row = np.asarray(logits)[0]
-            if req.temperature > 0.0:
-                self._key, sub = jax.random.split(self._key)
-                u = np.asarray(jax.random.uniform(sub, row.shape))
-                gumbel = -np.log(-np.log(u + 1e-9) + 1e-9)
-                first = int(np.argmax(row / req.temperature + gumbel))
-            else:
-                first = int(np.argmax(row))
-            jax.block_until_ready(logits)
-            if self.virtual and self.prefill_op is not None:
-                # modelled prefill duration: the operating point's profile
-                # is per prefill_seq tokens — scale to the tokens actually
-                # computed (the suffix only, under a prefix hit)
-                prof = self.prefill_op.profile
-                self.advance_time(prof.t_total * work / max(prof.tokens, 1))
-        finally:
-            dt = self.clock() - t0
-            self._in_phase_call = False
+        with span("prefill", req.uid):
+            l = len(req.prompt)
+            work = l if shared is None else l - shared.prefix_tokens
+            self._in_phase_call = True
             self._refresh_gauge()
-        mj = self._mj_per_token("prefill")
-        joules = mj * work / 1e3
-        self.stats.merge_prefill(work, dt, joules)
-        req.prefill_s += dt
-        req.prefill_j += joules
-        if shared is not None:
-            dp = donor if donor is not None else self
-            saved_j = mj * shared.prefix_tokens / 1e3
-            req.prefix_tokens = shared.prefix_tokens
-            req.saved_prefill_j += saved_j
-            dp.prefix_stats.saved_prefill_tokens += shared.prefix_tokens
-            dp.prefix_stats.saved_prefill_j += saved_j
-        return first, cache1
+            t0 = self.clock()
+            req.ledger.mark_admitted(t0)
+            try:
+                if precomputed is None:
+                    if shared is not None:
+                        dp = donor if donor is not None else self
+                        toks, true_len, _ = self.suffix_tokens(
+                            req, shared.prefix_tokens)
+                        logits, cache1 = dp.shared_prefill(
+                            self.params, toks, true_len, shared)
+                    else:
+                        toks, true_len, bucket = self.prefill_tokens(req)
+                        logits, cache1 = self._jit_prefill(
+                            self.params, toks, true_len, bucket=bucket
+                        )
+                    self.jit_dispatches += 1
+                else:
+                    logits, cache1 = precomputed
+                with span("prefill.sync"):
+                    row = np.asarray(logits)[0]
+                    if req.temperature > 0.0:
+                        self._key, sub = jax.random.split(self._key)
+                        u = np.asarray(jax.random.uniform(sub, row.shape))
+                        gumbel = -np.log(-np.log(u + 1e-9) + 1e-9)
+                        first = int(np.argmax(row / req.temperature + gumbel))
+                    else:
+                        first = int(np.argmax(row))
+                jax.block_until_ready(logits)
+                if self.virtual and self.prefill_op is not None:
+                    # modelled prefill duration: the operating point's profile
+                    # is per prefill_seq tokens — scale to the tokens actually
+                    # computed (the suffix only, under a prefix hit)
+                    prof = self.prefill_op.profile
+                    self.advance_time(prof.t_total * work / max(prof.tokens, 1))
+            finally:
+                dt = self.clock() - t0
+                self._in_phase_call = False
+                self._refresh_gauge()
+            mj = self._mj_per_token("prefill")
+            joules = mj * work / 1e3
+            self.stats.merge_prefill(work, dt, joules)
+            req.prefill_s += dt
+            req.prefill_j += joules
+            if shared is not None:
+                dp = donor if donor is not None else self
+                saved_j = mj * shared.prefix_tokens / 1e3
+                req.prefix_tokens = shared.prefix_tokens
+                req.saved_prefill_j += saved_j
+                dp.prefix_stats.saved_prefill_tokens += shared.prefix_tokens
+                dp.prefix_stats.saved_prefill_j += saved_j
+            return first, cache1
 
     def _place_bookkeeping(self, req: Request, first_token: int, length: int,
                            first_token_s: Optional[float]) -> int:
@@ -1379,46 +1383,47 @@ class Pool:
         referenced by ``req.uid``, so nothing is allocated or copied for
         them: the scatter is masked to the null page there, and the bytes
         the migration avoided are banked in ``prefix_stats``."""
-        slot = self._place_bookkeeping(req, first_token, length, first_token_s)
-        if self.paged:
-            if shared is None and self._prefix is not None:
-                # batched placement paths (place_many) don't thread the
-                # hit — re-find the one prefix_acquire pinned for this uid
-                shared = self._pending_hits.get(req.uid)
-            need = self.allocator.blocks_for_tokens(length + 1)
-            se = shared.shared_entries if shared is not None else 0
-            blocks = self._alloc_blocks(need - se, owner=req.uid)
-            page_map = np.full(self.block_tables.shape[1], NULL_PAGE, np.int32)
-            if se:
-                page_map[:se] = shared.table_blocks
-            page_map[se:need] = blocks
-            self.block_tables[slot] = page_map
-            scatter_map = page_map.copy()
-            if se:
-                scatter_map[:se] = NULL_PAGE      # shared pages: never written
-                self._pending_hits.pop(req.uid, None)
-            self.cache = self._jit_scatter_paged(
-                self.cache, cache1, jnp.asarray(scatter_map), slot
-            )
-            # copy-on-migrate moves the PRIVATE blocks of KV into the pool;
-            # shared entries move nothing (the avoided bytes are metered)
-            npriv = need - se
-            self.traffic.count_writes(
-                npriv, npriv * self.kv_block_size * self._kv_token_bytes
-                + self._state_write_bytes,
-            )
-            if se:
-                self.prefix_stats.saved_migrate_bytes += (
-                    se * self.kv_block_size * self._kv_token_bytes)
-        elif isinstance(self.cache, BankRow):
-            # write THROUGH the bank: the stacked tree is donated and
-            # replaced, so every other member pool's view follows along
-            self._bank_write(("bank_scatter_jit",), _bank_scatter_impl,
-                             cache1, np.int32(slot))
-        else:
-            self.cache = self._jit_scatter(self.cache, cache1, slot)
-        self.jit_dispatches += 1
-        return slot
+        with span("place", req.uid):
+            slot = self._place_bookkeeping(req, first_token, length, first_token_s)
+            if self.paged:
+                if shared is None and self._prefix is not None:
+                    # batched placement paths (place_many) don't thread the
+                    # hit — re-find the one prefix_acquire pinned for this uid
+                    shared = self._pending_hits.get(req.uid)
+                need = self.allocator.blocks_for_tokens(length + 1)
+                se = shared.shared_entries if shared is not None else 0
+                blocks = self._alloc_blocks(need - se, owner=req.uid)
+                page_map = np.full(self.block_tables.shape[1], NULL_PAGE, np.int32)
+                if se:
+                    page_map[:se] = shared.table_blocks
+                page_map[se:need] = blocks
+                self.block_tables[slot] = page_map
+                scatter_map = page_map.copy()
+                if se:
+                    scatter_map[:se] = NULL_PAGE      # shared pages: never written
+                    self._pending_hits.pop(req.uid, None)
+                self.cache = self._jit_scatter_paged(
+                    self.cache, cache1, jnp.asarray(scatter_map), slot
+                )
+                # copy-on-migrate moves the PRIVATE blocks of KV into the pool;
+                # shared entries move nothing (the avoided bytes are metered)
+                npriv = need - se
+                self.traffic.count_writes(
+                    npriv, npriv * self.kv_block_size * self._kv_token_bytes
+                    + self._state_write_bytes,
+                )
+                if se:
+                    self.prefix_stats.saved_migrate_bytes += (
+                        se * self.kv_block_size * self._kv_token_bytes)
+            elif isinstance(self.cache, BankRow):
+                # write THROUGH the bank: the stacked tree is donated and
+                # replaced, so every other member pool's view follows along
+                self._bank_write(("bank_scatter_jit",), _bank_scatter_impl,
+                                 cache1, np.int32(slot))
+            else:
+                self.cache = self._jit_scatter(self.cache, cache1, slot)
+            self.jit_dispatches += 1
+            return slot
 
     def place_many(self, items: Sequence[Tuple[Request, Any, int, int,
                                                Optional[float]]]) -> List[int]:
@@ -1435,24 +1440,25 @@ class Pool:
             return [self.place(req, cache1, first, length,
                                first_token_s=ts)
                     for req, cache1, first, length, ts in items]
-        slots = [self._place_bookkeeping(req, first, length, ts)
-                 for req, cache1, first, length, ts in items]
-        rows = [cache1 for _, cache1, _, _, _ in items]
-        pad_slots = list(slots)
-        p = 1 << (len(rows) - 1).bit_length()
-        rows.extend([rows[0]] * (p - len(rows)))
-        pad_slots.extend([pad_slots[0]] * (p - len(pad_slots)))
-        if isinstance(self.cache, BankRow):
-            self._bank_write(("bank_scatter_multi_jit", p),
-                             _bank_multi_scatter_impl, tuple(rows),
-                             tuple(pad_slots))
-        else:
-            fn = _cached(
-                ("scatter_multi_jit", self.cfg, self.max_seq_len, p),
-                lambda: jax.jit(_multi_scatter_impl, donate_argnums=(0,)))
-            self.cache = fn(self.cache, tuple(rows), tuple(pad_slots))
-        self.jit_dispatches += 1
-        return slots
+        with span("place", tuple(req.uid for req, *_ in items)):
+            slots = [self._place_bookkeeping(req, first, length, ts)
+                     for req, cache1, first, length, ts in items]
+            rows = [cache1 for _, cache1, _, _, _ in items]
+            pad_slots = list(slots)
+            p = 1 << (len(rows) - 1).bit_length()
+            rows.extend([rows[0]] * (p - len(rows)))
+            pad_slots.extend([pad_slots[0]] * (p - len(pad_slots)))
+            if isinstance(self.cache, BankRow):
+                self._bank_write(("bank_scatter_multi_jit", p),
+                                 _bank_multi_scatter_impl, tuple(rows),
+                                 tuple(pad_slots))
+            else:
+                fn = _cached(
+                    ("scatter_multi_jit", self.cfg, self.max_seq_len, p),
+                    lambda: jax.jit(_multi_scatter_impl, donate_argnums=(0,)))
+                self.cache = fn(self.cache, tuple(rows), tuple(pad_slots))
+            self.jit_dispatches += 1
+            return slots
 
     def _req_eos(self, req: Request) -> int:
         return self.eos_token_id if req.eos_token_id is None else req.eos_token_id
@@ -1471,32 +1477,33 @@ class Pool:
         ``args``; the batched engine path passes ``keep_view=True`` and
         resolves the view itself (either reusing the bank's stacked tree
         directly or gathering rows inside its own program)."""
-        if self.paged and any(r is not None for r in self.slot_req):
-            self._grow_tables()
-            if self._prefix is not None:
-                self._cow_guard()
-        active = self.active_mask()
-        if not active.any():
-            return None
-        if not keep_view:
-            self.materialize_cache()
-        self._ensure_decode_state()
-        self._key, sub = jax.random.split(self._key)
-        t0 = self.clock()
-        # ship the host mirrors once per step (placements only wrote numpy);
-        # jit moves numpy args to the device inside dispatch, so no eager
-        # per-array device_put is paid here. Copies because the mirrors
-        # mutate between this dispatch and the next placement.
-        toks = self._host_cur_token.copy()
-        lengths = self._host_lengths.astype(np.int32)
-        temps = self._slot_temp.copy()
-        if self.paged:
-            args = (self.params, toks, self.cache, lengths,
-                    active, self.block_tables.copy(), sub, temps)
-        else:
-            args = (self.params, toks, self.cache, lengths,
-                    active, sub, temps)
-        return {"active": active, "t0": t0, "args": args}
+        with span("decode.prepare"):
+            if self.paged and any(r is not None for r in self.slot_req):
+                self._grow_tables()
+                if self._prefix is not None:
+                    self._cow_guard()
+            active = self.active_mask()
+            if not active.any():
+                return None
+            if not keep_view:
+                self.materialize_cache()
+            self._ensure_decode_state()
+            self._key, sub = jax.random.split(self._key)
+            t0 = self.clock()
+            # ship the host mirrors once per step (placements only wrote numpy);
+            # jit moves numpy args to the device inside dispatch, so no eager
+            # per-array device_put is paid here. Copies because the mirrors
+            # mutate between this dispatch and the next placement.
+            toks = self._host_cur_token.copy()
+            lengths = self._host_lengths.astype(np.int32)
+            temps = self._slot_temp.copy()
+            if self.paged:
+                args = (self.params, toks, self.cache, lengths,
+                        active, self.block_tables.copy(), sub, temps)
+            else:
+                args = (self.params, toks, self.cache, lengths,
+                        active, sub, temps)
+            return {"active": active, "t0": t0, "args": args}
 
     def decode_once(self) -> List[Request]:
         """One jitted decode step over all slots; returns finished requests.
@@ -1507,7 +1514,8 @@ class Pool:
         if pre is None:
             return []
         jit_fn = self._jit_decode_paged if self.paged else self._jit_decode
-        next_tok, cache, lengths = jit_fn(*pre["args"])
+        with span("decode.dispatch"):
+            next_tok, cache, lengths = jit_fn(*pre["args"])
         self.jit_dispatches += 1
         return self._decode_finish(pre, next_tok, cache, lengths)
 
@@ -1515,12 +1523,19 @@ class Pool:
         """Second half of ``decode_once``: adopt the jitted step's outputs,
         advance the (virtual) clock by the modelled step duration, and do
         the per-slot token/energy/EOS accounting."""
+        with span("decode.sync"):
+            next_np = np.asarray(next_tok)
+        with span("decode.account"):
+            return self._decode_account(pre, next_np, next_tok, cache, lengths)
+
+    def _decode_account(self, pre: dict, next_np: np.ndarray, next_tok, cache,
+                        lengths) -> List[Request]:
+        """``_decode_finish`` once the step's tokens are on the host."""
         self.cache = cache
         self.lengths = lengths
         active = pre["active"]
         t0 = pre["t0"]
         finished: List[Request] = []
-        next_np = np.asarray(next_tok)
         if self.virtual and self.op is not None:
             # the modelled step duration at the live operating point IS the
             # virtual-time cost of this decode step

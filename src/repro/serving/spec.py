@@ -215,7 +215,7 @@ class ReplicaSpec:
 # options like on_finish stay out: a spec must stay JSON-round-trippable)
 ENGINE_OPT_KEYS = (
     "fast_path_min", "fusion_quantum_s", "fuse_prefill", "max_fused_group",
-    "fused_cache_cap", "batch_replicas", "batch_layout", "time_dispatch",
+    "fused_cache_cap", "batch_replicas", "batch_layout",
 )
 
 

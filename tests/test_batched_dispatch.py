@@ -36,6 +36,7 @@ from repro.serving import (
 )
 from repro.serving import events as events_mod
 from repro.serving import pool as pool_mod
+from repro.serving import spans
 from repro.serving.fleet import Replica
 
 ARCH = "gemma-2b"
@@ -283,17 +284,31 @@ class TestBatchedStats:
         assert b.bank_rebuilds <= b.batched_decode_calls
 
     def test_dispatch_wall_clock_ledger(self, setup):
-        """``time_dispatch=True`` records per-group-size wall seconds for
-        the dispatch-vs-group-size curve; the call counts must add up to
-        the fused dispatches and the dict must survive as_dict/json."""
-        fleet, _ = _run(setup, _aligned_trace(), time_dispatch=True)
+        """The span recorder's ``decode.fused`` records time each fused
+        group for the dispatch-vs-group-size curve: one record per fused
+        dispatch, carrying its pow2-padded group size, with every member's
+        ``decode.sync``/``decode.account`` nested inside it."""
+        spans.clear()
+        spans.enable()
+        try:
+            fleet, _ = _run(setup, _aligned_trace())
+        finally:
+            spans.disable()
+        recs = list(spans.records())
+        spans.clear()
         st = fleet.last_engine_stats
-        assert st.fused_decode_wall, "no timings recorded"
-        calls = sum(int(v[0]) for v in st.fused_decode_wall.values())
-        assert calls == st.fused_decode_calls
-        assert all(v[1] >= 0.0 for v in st.fused_decode_wall.values())
-        assert all(int(k) > 0 and (int(k) & (int(k) - 1)) == 0
-                   for k in st.fused_decode_wall)
+        fused = [i for i, r in enumerate(recs) if r[0] == "decode.fused"]
+        assert fused, "no fused group recorded"
+        assert len(fused) == st.fused_decode_calls
+        assert all(recs[i][2] >= recs[i][1] for i in fused)
+        sizes = [recs[i][4] for i in fused]
+        assert all(k > 0 and (k & (k - 1)) == 0 for k in sizes)
+        assert st.pad_waste == sum(sizes) - sum(
+            r[0] == "decode.sync" and r[3] in fused for r in recs)
+        for i in fused:
+            kids = [r for r in recs if r[3] == i]
+            assert {r[0] for r in kids} == {"decode.sync", "decode.account"}
+            assert all(recs[i][1] <= r[1] and r[2] <= recs[i][2] for r in kids)
         json.dumps(st.as_dict())
 
     def test_batched_keys_reuse_decode_kind(self, setup):
