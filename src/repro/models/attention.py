@@ -4,8 +4,10 @@ Cache convention
 ----------------
 A self-attention cache is a dict ``{"k": (B, L_max, n_kv, hd), "v": ...}``
 plus an external per-example ``lengths: (B,) int32`` giving the number of
-valid tokens already cached. ``decode_step`` writes the new token at
-``lengths`` and attends over ``lengths + 1`` entries. Cross-attention caches
+valid tokens already cached. A decode step attends over ``lengths + 1``
+entries, its new token's row laid over the cache at ``lengths`` for the
+attention alone, and returns that row: the model writes every layer's rows
+into the cache after its layer scan (``_write_at_lengths``). Cross-attention caches
 encoder K/V once at prefill; decode reuses them unchanged (the paper's
 vision-layer semantics).
 
@@ -170,25 +172,26 @@ def self_attention_prefill_suffix(
 
 @jax.named_scope("kv_write")
 def _paged_token_write(
-    pages: jax.Array,         # (P, bs, ...) physical pages; page 0 reserved/null
-    new: jax.Array,           # (B, 1, ...) the new token's row per request
+    pages: jax.Array,         # (n_units, P, bs, ...) physical pages; page 0 reserved/null
+    new: jax.Array,           # (n_units, B, 1, ...) each layer's new row per request
     block_tables: jax.Array,  # (B, nb) logical block -> physical page id
     lengths: jax.Array,       # (B,) tokens already cached (write position)
     active: jax.Array,        # (B,) bool; inactive slots write to the null page
 ) -> jax.Array:
-    """Per-request cache write through the block table.
+    """Per-request cache write through the block table, every layer at once.
 
     The dense path writes slot-private rows, so stale lengths on inactive
     slots are harmless; with paging a stale table could point at a page
     since reallocated to another request, so inactive writes are routed to
-    the reserved null page 0 instead.
+    the reserved null page 0 instead. So is a slot at ``lengths == nb * bs``,
+    which, as on the dense path, writes nothing of its own.
     """
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     nb = block_tables.shape[1]
     blk = jnp.clip(lengths // bs, 0, nb - 1)
     phys = jnp.take_along_axis(block_tables, blk[:, None], axis=1)[:, 0]
-    phys = jnp.where(active, phys, 0)
-    return pages.at[phys, lengths % bs].set(new[:, 0].astype(pages.dtype))
+    phys = jnp.where(active & (lengths < nb * bs), phys, 0)
+    return pages.at[:, phys, lengths % bs].set(new[:, :, 0].astype(pages.dtype))
 
 
 def _gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
@@ -200,13 +203,22 @@ def _gather_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
 
 @jax.named_scope("kv_write")
 def _write_at_lengths(buf: jax.Array, new: jax.Array, lengths: jax.Array) -> jax.Array:
-    """Per-example cache write at ragged positions: buf (B,L,...), new (B,1,...).
+    """Per-example cache write at ragged positions, every layer at once:
+    buf (n_units, B, L, ...), new (n_units, B, 1, ...), lengths (B,).
 
-    Mask-select formulation (§Perf iteration 3): one fused elementwise pass
-    that stays local under ANY sharding of the L axis — the vmap'd
-    dynamic-update-slice alternative forces SPMD gather/select chains on a
-    sequence-sharded cache.
+    One scatter of ``n_units * B`` rows, in place when ``buf`` is donated,
+    and correct under any sharding. A slot at ``lengths == L`` writes
+    nothing: the scatter drops out-of-range rows.
     """
+    slots = jnp.arange(buf.shape[1])
+    return buf.at[:, slots, lengths].set(new[:, :, 0].astype(buf.dtype), mode="drop")
+
+
+@jax.named_scope("attn")
+def _with_row_at_lengths(buf: jax.Array, new: jax.Array, lengths: jax.Array) -> jax.Array:
+    """The view a decode step attends over: buf (B, L, ...) with each
+    slot's new row new (B, 1, ...) in place of position ``lengths``. Only
+    the attention reads it, so it fuses there and the cache is not written."""
     l = buf.shape[1]
     mask = jnp.arange(l)[None, :] == lengths[:, None]          # (B, L)
     mask = mask.reshape(mask.shape + (1,) * (buf.ndim - 2))
@@ -251,11 +263,16 @@ def self_attention_decode(
     *,
     is_global: bool,
 ) -> Tuple[jax.Array, Dict]:
+    """One decode step over a dense cache, read only: returns the output
+    and the new token's K/V rows ``{"k", "v"}: (B, 1, KV, hd)``, which the
+    caller writes at ``lengths``."""
     q, k_new, v_new = _decode_qkv(params, x, lengths, cfg)
-    k_buf = _write_at_lengths(cache["k"], k_new.astype(cache["k"].dtype), lengths)
-    v_buf = _write_at_lengths(cache["v"], v_new.astype(cache["v"].dtype), lengths)
+    k_new = k_new.astype(cache["k"].dtype)
+    v_new = v_new.astype(cache["v"].dtype)
+    k_buf = _with_row_at_lengths(cache["k"], k_new, lengths)
+    v_buf = _with_row_at_lengths(cache["v"], v_new, lengths)
     out = _decode_attend(params, q, k_buf, v_buf, lengths, cfg, is_global, x.dtype)
-    return out, {"k": k_buf, "v": v_buf}
+    return out, {"k": k_new, "v": v_new}
 
 
 def self_attention_decode_paged(
@@ -264,13 +281,14 @@ def self_attention_decode_paged(
     cache: Dict,                     # {"k": (P, bs, KV, hd), "v": ...} pages
     block_tables: jax.Array,         # (B, nb)
     lengths: jax.Array,              # (B,)
-    active: jax.Array,               # (B,) bool
     cfg,
     *,
     is_global: bool,
 ) -> Tuple[jax.Array, Dict]:
-    """Decode over the PAGED cache layout: write the new token through the
-    block table, gather the table's pages to a contiguous view, attend.
+    """Decode over the PAGED cache layout, read only: gather the table's
+    pages to a contiguous view, lay the new token's row over it, attend;
+    returns the output and the new K/V rows, which the caller writes
+    through the block table (``_paged_token_write``).
 
     Same math as ``self_attention_decode`` — paging is pure layout — which
     is what the paged==dense property tests pin down. (On TPU the gather+
@@ -278,12 +296,12 @@ def self_attention_decode_paged(
     streams exactly the pages the table names.)
     """
     q, k_new, v_new = _decode_qkv(params, x, lengths, cfg)
-    k_pages = _paged_token_write(cache["k"], k_new, block_tables, lengths, active)
-    v_pages = _paged_token_write(cache["v"], v_new, block_tables, lengths, active)
-    k_buf = _gather_pages(k_pages, block_tables)
-    v_buf = _gather_pages(v_pages, block_tables)
+    k_new = k_new.astype(cache["k"].dtype)
+    v_new = v_new.astype(cache["v"].dtype)
+    k_buf = _with_row_at_lengths(_gather_pages(cache["k"], block_tables), k_new, lengths)
+    v_buf = _with_row_at_lengths(_gather_pages(cache["v"], block_tables), v_new, lengths)
     out = _decode_attend(params, q, k_buf, v_buf, lengths, cfg, is_global, x.dtype)
-    return out, {"k": k_pages, "v": v_pages}
+    return out, {"k": k_new, "v": v_new}
 
 
 # ----------------------------------------------------------------- cross-attn

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.attention import NEG_INF, _gather_pages, _paged_token_write, _write_at_lengths
+from repro.models.attention import NEG_INF, _gather_pages, _with_row_at_lengths
 from repro.models.flash import attention_prefill_auto
 from repro.models.layers import apply_rope, rmsnorm, init_rmsnorm
 
@@ -172,22 +172,10 @@ def mla_decode(
     *,
     absorb: bool,
 ) -> Tuple[jax.Array, Dict]:
-    positions = lengths[:, None]
-    with jax.named_scope("attn"):
-        q_nope, q_rope = _queries(params, x, positions, cfg)
-        ckv_new, kr_new = _latents(params, x, positions, cfg)
-
-    ckv_buf = _write_at_lengths(cache["ckv"], ckv_new, lengths)
-    kr_buf = _write_at_lengths(cache["kr"], kr_new, lengths)
-
-    with jax.named_scope("attn"):
-        l_max = ckv_buf.shape[1]
-        mask = (jnp.arange(l_max)[None, :] <= lengths[:, None])[:, None, None, :]
-        attend = _attend_absorbed if absorb else _attend_naive
-        out = attend(
-            params, q_nope, q_rope, ckv_buf.astype(x.dtype), kr_buf.astype(x.dtype), mask, cfg, x.dtype
-        )
-    return out, {"ckv": ckv_buf, "kr": kr_buf}
+    """One decode step over the dense latent cache, read only: returns the
+    output and the new token's latent rows ``{"ckv", "kr"}: (B, 1, ...)``,
+    which the caller writes at ``lengths``."""
+    return _decode_over(params, x, cache["ckv"], cache["kr"], lengths, cfg, absorb)
 
 
 def mla_decode_paged(
@@ -196,32 +184,36 @@ def mla_decode_paged(
     cache: Dict,                    # {"ckv": (P, bs, rank), "kr": (P, bs, rope)}
     block_tables: jax.Array,        # (B, nb)
     lengths: jax.Array,             # (B,)
-    active: jax.Array,              # (B,) bool
     cfg,
     *,
     absorb: bool,
 ) -> Tuple[jax.Array, Dict]:
-    """Absorbed MLA decode over the PAGED latent cache: write the new
-    latent through the block table, gather the table's pages, attend. Same
-    math as ``mla_decode`` — and the compressed cache makes each page
+    """Absorbed MLA decode over the PAGED latent cache, read only: gather
+    the table's pages, lay the new latent over them, attend; the caller
+    writes the returned rows through the block table. Same math as
+    ``mla_decode`` — and the compressed cache makes each page
     ``(rank + rope) * bs`` bytes, the 3.6x traffic reduction the paged
     traffic meter makes visible per block. TPU kernel counterpart:
     ``kernels.mla_decode.mla_paged_fused_decode``."""
+    return _decode_over(params, x, _gather_pages(cache["ckv"], block_tables),
+                        _gather_pages(cache["kr"], block_tables), lengths, cfg, absorb)
+
+
+def _decode_over(params, x, ckv_cache, kr_cache, lengths, cfg, absorb):
+    """Decode attention over contiguous latent buffers (B, L, ...) with the
+    new token's latents laid over position ``lengths``."""
     positions = lengths[:, None]
     with jax.named_scope("attn"):
         q_nope, q_rope = _queries(params, x, positions, cfg)
         ckv_new, kr_new = _latents(params, x, positions, cfg)
-
-    ckv_pages = _paged_token_write(cache["ckv"], ckv_new, block_tables, lengths, active)
-    kr_pages = _paged_token_write(cache["kr"], kr_new, block_tables, lengths, active)
-    ckv_buf = _gather_pages(ckv_pages, block_tables)
-    kr_buf = _gather_pages(kr_pages, block_tables)
-
-    with jax.named_scope("attn"):
+        ckv_new = ckv_new.astype(ckv_cache.dtype)
+        kr_new = kr_new.astype(kr_cache.dtype)
+        ckv_buf = _with_row_at_lengths(ckv_cache, ckv_new, lengths)
+        kr_buf = _with_row_at_lengths(kr_cache, kr_new, lengths)
         l_max = ckv_buf.shape[1]
         mask = (jnp.arange(l_max)[None, :] <= lengths[:, None])[:, None, None, :]
         attend = _attend_absorbed if absorb else _attend_naive
         out = attend(
             params, q_nope, q_rope, ckv_buf.astype(x.dtype), kr_buf.astype(x.dtype), mask, cfg, x.dtype
         )
-    return out, {"ckv": ckv_pages, "kr": kr_pages}
+    return out, {"ckv": ckv_new, "kr": kr_new}

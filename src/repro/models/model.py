@@ -257,9 +257,12 @@ def _block_apply(
     shared_params: Optional[Dict],
     enc_states: Optional[jax.Array],
     block_tables: Optional[jax.Array] = None,   # paged decode only
-    active: Optional[jax.Array] = None,
     prefix_len: Optional[jax.Array] = None,     # suffix prefill only
 ) -> Tuple[jax.Array, Optional[Dict]]:
+    """One block: ``(x, what it writes)``. In train and prefill mode that is
+    the block's new cache; in decode mode it is what ``_decode_write``
+    persists after the layer scan: the new token's rows for a per-token
+    cache, the whole new state for ssm/gdn, nothing for cross-attention."""
     if kind == "shared_attn":
         bp = shared_params
         kind_eff = "attn_global"
@@ -276,7 +279,7 @@ def _block_apply(
         h = rmsnorm(bp["norm1"], x, cfg.rms_eps)
         if mode == "decode" and block_tables is not None:
             a_out, new_cache = attn.self_attention_decode_paged(
-                bp["attn"], h, cache, block_tables, lengths, active, cfg,
+                bp["attn"], h, cache, block_tables, lengths, cfg,
                 is_global=is_global,
             )
         elif mode == "decode":
@@ -308,9 +311,9 @@ def _block_apply(
                 "k": enc_cache["k"].astype(cache["k"].dtype),
                 "v": enc_cache["v"].astype(cache["v"].dtype),
             }
-        else:  # decode: reuse cached encoder K/V
+        else:  # decode: reuse cached encoder K/V, write nothing
             enc_cache = cache
-            new_cache = cache
+            new_cache = None
         a_out = attn.cross_attention_apply(bp["xattn"], h, enc_cache, cfg)
         x = x + a_out
         h = rmsnorm(bp["norm2"], x, cfg.rms_eps)
@@ -321,7 +324,7 @@ def _block_apply(
         h = rmsnorm(bp["norm1"], x, cfg.rms_eps)
         if mode == "decode" and block_tables is not None:
             a_out, new_cache = mla_mod.mla_decode_paged(
-                bp["mla"], h, cache, block_tables, lengths, active, cfg, absorb=True
+                bp["mla"], h, cache, block_tables, lengths, cfg, absorb=True
             )
         elif mode == "decode":
             a_out, new_cache = mla_mod.mla_decode(
@@ -368,6 +371,21 @@ def _block_apply(
     raise ValueError(kind)
 
 
+def _decode_write(kind: str, cache: Dict, out: Dict, lengths: jax.Array,
+                  block_tables: Optional[jax.Array],
+                  active: Optional[jax.Array]) -> Dict:
+    """One block's stacked decode cache (leaves ``(n_units, ...)``) after a
+    step, from what its units returned: per-token caches get every layer's
+    new row written in place, at ``lengths`` or through the block table;
+    ssm/gdn states are replaced whole; cross-attention's stays as it is."""
+    if kind in PAGED_KINDS:
+        if block_tables is None:
+            return {n: attn._write_at_lengths(cache[n], out[n], lengths) for n in cache}
+        return {n: attn._paged_token_write(cache[n], out[n], block_tables, lengths, active)
+                for n in cache}
+    return cache if kind == "cross_attn" else out
+
+
 def _run_stages(
     params: Dict,
     cfg: ModelConfig,
@@ -381,6 +399,11 @@ def _run_stages(
     active: Optional[jax.Array] = None,
     prefix_len: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Dict]]:
+    """Scan each stage's units. Train and prefill stream the stacked cache
+    through the scan (``xs`` in, ``ys`` out). Decode only reads it inside
+    the scan: the units' new rows leave as a small ``ys`` and are written
+    once, in place, afterwards (``_decode_write``), so the cache is never
+    copied whole."""
     shared = params.get("shared_block")
     new_stage_caches = []
     for si, stage in enumerate(cfg.stages):
@@ -394,7 +417,7 @@ def _run_stages(
                 bc = uc[f"b{i}"] if uc is not None else None
                 carry_x, nbc = _block_apply(
                     kind, up[f"b{i}"], carry_x, cfg, mode, bc, lengths, shared,
-                    enc_states, block_tables, active, prefix_len,
+                    enc_states, block_tables, prefix_len,
                 )
                 new_uc[f"b{i}"] = nbc if nbc is not None else {}
             # keep activations batch-sharded across unit boundaries (no-op
@@ -411,14 +434,17 @@ def _run_stages(
                 uc_u = jax.tree.map(lambda a, _u=u: a[_u], sc) if sc is not None else None
                 x, nuc = body(x, (up_u, uc_u))
                 new_units.append(nuc)
-            if sc is not None:
-                new_sc = jax.tree.map(lambda *ls: jnp.stack(ls), *new_units)
-                new_stage_caches.append(new_sc)
+            new_sc = jax.tree.map(lambda *ls: jnp.stack(ls), *new_units)
         elif sc is not None:
             x, new_sc = jax.lax.scan(body, x, (sp, sc))
-            new_stage_caches.append(new_sc)
         else:
             x, _ = jax.lax.scan(lambda c, p, _b=body: (_b(c, (p, None))[0], None), x, sp)
+        if sc is not None:
+            if mode == "decode":
+                new_sc = {f"b{i}": _decode_write(kind, sc[f"b{i}"], new_sc[f"b{i}"], lengths,
+                                                 block_tables, active)
+                          for i, kind in enumerate(stage.unit)}
+            new_stage_caches.append(new_sc)
     new_cache = {"stages": new_stage_caches} if cache is not None else None
     return x, new_cache
 

@@ -1,6 +1,7 @@
 """Model assembly tests: every block kind, prefill<->decode equivalence,
 cache semantics, MoE routing invariants."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -11,12 +12,15 @@ from repro.models import (
     ModelConfig,
     StageSpec,
     decode_step,
+    decode_step_batched,
     forward,
     init_cache,
     init_params,
     logits,
     prefill,
 )
+from repro.models import model as M
+from repro.models.layers import rmsnorm
 from repro.models.moe import moe_mlp, init_moe, _capacity
 
 
@@ -123,6 +127,158 @@ def test_ragged_batch_decode():
     )
     np.testing.assert_allclose(np.asarray(lg[0]), np.asarray(ref1[0]), rtol=3e-4, atol=3e-4)
     np.testing.assert_allclose(np.asarray(lg[1]), np.asarray(ref2[0]), rtol=3e-4, atol=3e-4)
+
+
+L_DECODE = 6
+
+
+def _random_cache(cfg, batch, seed):
+    """A decode cache of random contents (every leaf, every kind)."""
+    cache = init_cache(cfg, batch, L_DECODE)
+    leaves, tree = jax.tree.flatten(cache)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return jax.tree.unflatten(tree, [
+        jax.random.normal(k, x.shape).astype(x.dtype) for k, x in zip(keys, leaves)])
+
+
+def _decode_token(cfg, batch, seed):
+    key = jax.random.PRNGKey(seed)
+    if cfg.input_is_embeddings:
+        return jax.random.normal(key, (batch, 1, cfg.d_model))
+    return jax.random.randint(key, (batch,), 0, cfg.vocab_size)
+
+
+def _with_row(buf, row, lengths):
+    """buf (B, L, ...) with row (B, 1, ...) at each slot's ``lengths``."""
+    at = jnp.arange(buf.shape[1])[None, :] == lengths[:, None]
+    return jnp.where(at.reshape(at.shape + (1,) * (buf.ndim - 2)), row, buf)
+
+
+def _reference_decode(params, cfg, token, cache, lengths):
+    """The full-buffer formula, scanned layer by layer: each per-token
+    cache takes its new row with a ``where`` over the whole buffer, the
+    block attends over that buffer, and the scan stacks the buffers; ssm and
+    gdn states are replaced; the cross-attention cache stays."""
+    x = (token.astype(M._cdtype(cfg)) if cfg.input_is_embeddings
+         else M._embed_inputs(params, cfg, token[:, None]))
+    shared = params.get("shared_block")
+
+    def unit(x, xs, stage):
+        up, uc = xs
+        new = {}
+        for i, kind in enumerate(stage.unit):
+            b, bc = f"b{i}", uc[f"b{i}"]
+            x_out, out = M._block_apply(kind, up[b], x, cfg, "decode", bc, lengths,
+                                        shared, None)
+            if kind in M.PAGED_KINDS:
+                new[b] = {n: _with_row(bc[n], out[n], lengths) for n in bc}
+                x_out, _ = M._block_apply(kind, up[b], x, cfg, "decode", new[b], lengths,
+                                          shared, None)
+            else:
+                new[b] = bc if kind == "cross_attn" else out
+            x = x_out
+        return x, new
+
+    stages = []
+    for si, stage in enumerate(cfg.stages):
+        x, sc = jax.lax.scan(functools.partial(unit, stage=stage), x,
+                             (params["stages"][si], cache["stages"][si]))
+        stages.append(sc)
+    x = rmsnorm(params["final_norm"], x, cfg.rms_eps)
+    return logits(params, cfg, x)[:, 0], {"stages": stages}
+
+
+def _assert_same(got, want):
+    jax.tree.map(lambda g, w: np.testing.assert_array_equal(np.asarray(g), np.asarray(w)),
+                 got, want)
+
+
+@pytest.mark.parametrize("case", ["ragged", "full_slot", "inactive", "batched"])
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_decode_step_writes_rows_like_the_full_buffer_formula(name, case):
+    """``decode_step`` (rows written in place after the layer scan) gives
+    bit for bit the logits and caches of the reference formula: at ragged
+    lengths; with a slot at ``lengths == max_len``, which writes nothing;
+    through the pool's step with an inactive slot, which writes its own row
+    and keeps its length; and as ``decode_step_batched`` over two replicas."""
+    cfg = CASES[name]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    lengths = jnp.array([L_DECODE if case == "full_slot" else 2, 4], jnp.int32)
+    if case == "batched":
+        stack = lambda *a: jnp.stack(a)
+        cache = jax.tree.map(stack, _random_cache(cfg, 2, 5), _random_cache(cfg, 2, 6))
+        token = stack(_decode_token(cfg, 2, 7), _decode_token(cfg, 2, 8))
+        lengths = stack(lengths, lengths[::-1])
+        got = decode_step_batched(params, cfg, token, cache, lengths)
+        want = jax.vmap(lambda t, c, n: _reference_decode(params, cfg, t, c, n))(
+            token, cache, lengths)
+        _assert_same(got, want + (lengths + 1,))
+        return
+    cache = _random_cache(cfg, 2, 5)
+    token = _decode_token(cfg, 2, 7)
+    want_lg, want_cache = _reference_decode(params, cfg, token, cache, lengths)
+    if case == "inactive":
+        from repro.serving.pool import decode_impl_for
+
+        active = jnp.array([False, True])
+        tok, got_cache, new_len = decode_impl_for(cfg)(
+            params, token, cache, lengths, active, jax.random.PRNGKey(0), jnp.zeros(2))
+        np.testing.assert_array_equal(np.asarray(tok), np.argmax(np.asarray(want_lg), -1))
+        np.testing.assert_array_equal(np.asarray(new_len), [2, 5])
+    else:
+        got_lg, got_cache, _ = decode_step(params, cfg, token, cache, lengths)
+        np.testing.assert_array_equal(np.asarray(got_lg), np.asarray(want_lg))
+    _assert_same(got_cache, want_cache)
+    if case == "full_slot":   # the full slot's per-token rows are as they were
+        for stage, got, before in zip(cfg.stages, got_cache["stages"], cache["stages"]):
+            for i, kind in enumerate(stage.unit):
+                if kind in M.PAGED_KINDS:
+                    _assert_same(jax.tree.map(lambda a: a[:, 0], got[f"b{i}"]),
+                                 jax.tree.map(lambda a: a[:, 0], before[f"b{i}"]))
+
+
+@pytest.mark.parametrize("name", ["gdn", "hybrid", "moe", "vlm"])   # every block kind
+def test_unrolled_decode_matches_the_scanned_one(name):
+    """The accounting lowering (units in a Python loop) writes the same
+    caches as the scan; XLA may fuse the two programs apart, so values
+    agree to float32 rounding."""
+    from repro.models.unroll import set_unroll
+
+    cfg = CASES[name]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    args = (params, cfg, _decode_token(cfg, 2, 7), _random_cache(cfg, 2, 5),
+            jnp.array([2, 4], jnp.int32))
+    scanned = decode_step(*args)
+    set_unroll(True)
+    try:
+        unrolled = decode_step(*args)
+    finally:
+        set_unroll(False)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6), unrolled, scanned)
+
+
+def test_a_stale_row_write_shows_on_the_next_step(monkeypatch):
+    """The seam the benchmark's fault check relies on: with
+    ``attention._write_at_lengths`` made to return the buffer unchanged,
+    a step still sees its own new row, but the next step differs."""
+    from repro.models import attention
+
+    cfg = CASES["gqa"]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    lengths = jnp.array([1, 3], jnp.int32)
+
+    def two_steps():
+        cache = _random_cache(cfg, 2, 5)
+        lg1, cache, ln = decode_step(params, cfg, _decode_token(cfg, 2, 7), cache, lengths)
+        lg2, _, _ = decode_step(params, cfg, _decode_token(cfg, 2, 8), cache, ln)
+        return np.asarray(lg1), np.asarray(lg2)
+
+    good = two_steps()
+    monkeypatch.setattr(attention, "_write_at_lengths", lambda buf, new, lengths: buf)
+    stale = two_steps()
+    np.testing.assert_array_equal(stale[0], good[0])
+    assert np.abs(stale[1] - good[1]).max() > 1e-3
 
 
 class TestMoE:

@@ -236,11 +236,10 @@ class TestPagedDenseEquivalence:
             assert a.output == b.output
         assert paged.pool.allocator.used_blocks == 0  # all blocks returned
 
-    def test_model_level_logits_match(self, setup):
-        """decode_step_paged == decode_step on the same migrated prefill
-        rows — paging is pure layout, checked at the logits level."""
-        cfg, params = setup
-        B, L_max, bs = 2, 32, 8
+    @staticmethod
+    def _migrated(cfg, params, B=2, L_max=32, bs=8):
+        """Two prefilled prompts laid into a dense cache and, page by page,
+        into a paged one: (dense, paged, tables, lengths, first tokens)."""
         nb = L_max // bs
         prompts = [np.arange(1, 6, dtype=np.int32), np.arange(2, 12, dtype=np.int32)]
 
@@ -275,20 +274,70 @@ class TestPagedDenseEquivalence:
                 return jax.lax.dynamic_update_slice_in_dim(big, small, _b, axis=1)
 
             paged = jax.tree.map(scat, paged, c1, layout)
+        return dense, paged, jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(toks)
 
-        lengths = jnp.asarray(lengths)
-        tok = jnp.asarray(toks)
-        active = jnp.ones(B, bool)
+    def test_model_level_logits_match(self, setup):
+        """decode_step_paged == decode_step on the same migrated prefill
+        rows — paging is pure layout, checked at the logits level."""
+        cfg, params = setup
+        dense, paged, tables, lengths, tok = self._migrated(cfg, params)
+        active = jnp.ones(2, bool)
         dl = pl_ = lengths
         dt_ = pt_ = tok
         for _ in range(3):
             lg_d, dense, dl = decode_step(params, cfg, dt_, dense, dl)
             lg_p, paged, pl_ = decode_step_paged(
-                params, cfg, pt_, paged, pl_, active, jnp.asarray(tables))
+                params, cfg, pt_, paged, pl_, active, tables)
             np.testing.assert_allclose(
                 np.asarray(lg_d), np.asarray(lg_p), rtol=1e-5, atol=1e-5)
             dt_ = jnp.argmax(lg_d, -1).astype(jnp.int32)
             pt_ = jnp.argmax(lg_p, -1).astype(jnp.int32)
+
+    @pytest.mark.parametrize("case", ["ragged", "full_slot", "inactive"])
+    def test_model_level_step_writes_like_dense(self, setup, case):
+        """One paged step on the migrated rows, bit for bit against the
+        dense step (itself held to the full-buffer formula in
+        test_models): the logits of every active slot; each active slot's
+        pages, read through its table, hold the dense cache's rows; a slot
+        at ``lengths == max_len`` changes no page of its own; an inactive
+        slot writes its row to the null page and nothing else."""
+        cfg, params = setup
+        dense, paged, tables, lengths, tok = self._migrated(cfg, params)
+        L_max = dense["stages"][0]["b0"]["k"].shape[2]
+        if case == "full_slot":   # slot 0 holds all its pages (free, zero) and is full
+            nb = tables.shape[1]
+            tables = tables.at[0, 1:].set(1 + 2 * nb - jnp.arange(1, nb))
+            lengths = lengths.at[0].set(L_max)
+        active = jnp.array([case != "inactive", True])
+        lg_d, dense2, _ = decode_step(params, cfg, tok, dense, lengths)
+        lg_p, paged2, _ = decode_step_paged(params, cfg, tok, paged, lengths, active, tables)
+        act = np.asarray(active)
+        np.testing.assert_array_equal(np.asarray(lg_p)[act], np.asarray(lg_d)[act])
+
+        def view(pages):  # (n, P, bs, ...) -> (n, B, nb*bs, ...)
+            b, nb = tables.shape
+            return pages[:, tables].reshape(pages.shape[0], b, -1, *pages.shape[3:])
+
+        bs = paged["stages"][0]["b0"]["k"].shape[2]
+        own = np.repeat(np.asarray(tables) != 0, bs, axis=1)   # (B, nb*bs)
+        for d_after, p_before, p_after, is_paged in zip(
+                *(jax.tree.leaves(t) for t in (dense2, paged, paged2, paged_layout(cfg)))):
+            if not is_paged:
+                np.testing.assert_array_equal(np.asarray(p_after), np.asarray(d_after))
+                continue
+            v_after, v_before = np.asarray(view(p_after)), np.asarray(view(p_before))
+            for b in range(2):
+                n, mine = int(lengths[b]), own[b]
+                if case == "inactive" and b == 0:
+                    np.testing.assert_array_equal(v_after[:, b, mine], v_before[:, b, mine])
+                    np.testing.assert_array_equal(np.asarray(p_after[:, 0, n % bs]),
+                                                  np.asarray(d_after[:, b, n]))
+                    continue
+                if case == "full_slot" and b == 0:
+                    np.testing.assert_array_equal(v_after[:, b, mine], v_before[:, b, mine])
+                mine = mine & (np.arange(mine.size) <= n)
+                np.testing.assert_array_equal(v_after[:, b, mine],
+                                              np.asarray(d_after[:, b, mine]))
 
     def test_cluster_paged_matches_dense_under_controller(self, setup):
         cfg, params = setup

@@ -9,6 +9,7 @@ compilation cache is off around these compiles — a program compiled for a
 described chip can be written to it but not read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,10 +112,23 @@ def _tree_bytes(tree):
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
+def _writes_cache_in_place(compiled, cache, n_layers):
+    """The step holds no second cache: its scratch stays under two layers'
+    share of the cache, and no op copies a whole stacked cache leaf."""
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * _tree_bytes(cache) // n_layers, f"{temp} B of scratch"
+    text = compiled.as_text()
+    for leaf in jax.tree.leaves(cache):
+        dims = ",".join(map(str, leaf.shape))
+        copies = re.findall(rf"= \w+\[{dims}\]\{{[^}}]*\}} copy\(", text)
+        assert not copies, f"{len(copies)} copies of a cache leaf {leaf.shape}"
+
+
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 def test_qwen3_decode_step_fits_one_chip(one_chip, paged):
     """The pool's serial decode program at the smoke's pool shape compiles
-    for one v5e chip with its cache donated, and fits its memory."""
+    for one v5e chip with its cache donated, fits its memory, and writes
+    its new rows into the donated cache in place."""
     from repro.serving.pool import decode_jit_for
 
     cfg = get_config("qwen3-4b")
@@ -133,6 +147,7 @@ def test_qwen3_decode_step_fits_one_chip(one_chip, paged):
             s((2,), jnp.uint32), s((BATCH,), F32))
     compiled = decode_jit_for(cfg, paged=paged).lower(*args).compile()
     _fits(compiled, _tree_bytes(cache))
+    _writes_cache_in_place(compiled, cache, cfg.n_blocks)
 
 
 def test_qwen3_mesh_decode_fits_four_chips(topo):
