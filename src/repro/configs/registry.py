@@ -16,6 +16,7 @@ from repro.configs import (
     deepseek_v2_lite_16b,
     gemma2_9b,
     gemma_2b,
+    granite_4_0_h_micro,
     llama_3_2_vision_11b,
     mamba2_780m,
     minicpm_2b,
@@ -50,6 +51,7 @@ _REGISTRY: Dict[str, Callable[[], ModelConfig]] = {
     "deepseek-v2-lite-16b": deepseek_v2_lite_16b.config,
     "deepseek-v2-236b": deepseek_v2_236b.config,
     "zamba2-1.2b": zamba2_1_2b.config,
+    "granite-4.0-h-micro": granite_4_0_h_micro.config,
     **PAPER_MODELS,
 }
 
@@ -67,6 +69,16 @@ def get_config(arch: str) -> ModelConfig:
         raise KeyError(f"unknown arch {arch!r}; have {sorted(_REGISTRY)}") from None
 
 
+# Reduced sizes that differ from the generic ones below: granite keeps one
+# whole period of its layer pattern (5 Mamba2, 1 attention, 4 Mamba2), its
+# Mamba2 heads of 64 with state 128, and GQA groups of two.
+_REDUCED = {
+    "granite-4.0-h-micro": dict(
+        stages=granite_4_0_h_micro.stages(1), n_heads=16, n_kv_heads=8, head_dim=4,
+        ssm_state=128, ssm_heads=2, ssm_head_dim=64),
+}
+
+
 def _shrink_stage(s: StageSpec, n_units: int) -> StageSpec:
     return StageSpec(unit=s.unit, n_units=min(s.n_units, n_units))
 
@@ -78,8 +90,7 @@ def reduced_config(arch: str) -> ModelConfig:
     kv = min(cfg.n_kv_heads, heads) if cfg.n_kv_heads else 0
     if kv and heads % kv:
         kv = 1
-    return dataclasses.replace(
-        cfg,
+    sizes = dict(
         name=cfg.name + "-reduced",
         d_model=64,
         vocab_size=512,
@@ -109,3 +120,5 @@ def reduced_config(arch: str) -> ModelConfig:
         compute_dtype="float32",
         max_seq_len=128,
     )
+    sizes.update(_REDUCED.get(arch, {}))
+    return dataclasses.replace(cfg, **sizes)

@@ -119,7 +119,7 @@ def _occupancy(cfg: ModelConfig, fused: bool):
     kind_map = {
         "attn": "attn", "attn_global": "attn", "shared_attn": "attn",
         "cross_attn": "attn", "mla": "mla", "mla_moe": "mla",
-        "ssm": "ssm", "gdn": "gdn",
+        "ssm": "ssm", "ssm_mlp": "ssm", "gdn": "gdn",
     }
     counts = _block_kind_counts(cfg)
     tot = sum(counts.values())
@@ -190,7 +190,7 @@ def decode_workload(
         bytes_ += b * latent * BYTES * n_mla                         # latent write
         kernels += (K_ATTN_LAYER + (0 if fused else K_MLA_EXTRA)) * n_mla
 
-    n_ssm = counts.get("ssm", 0)
+    n_ssm = counts.get("ssm", 0) + counts.get("ssm_mlp", 0)
     if n_ssm:
         d_inner = cfg.ssm_expand * d
         hs, p, n = cfg.ssm_heads, (cfg.ssm_expand * d) // cfg.ssm_heads, cfg.ssm_state
@@ -287,7 +287,7 @@ def prefill_workload(
         bytes_ += b * s * latent * BYTES * n_mla
         kernels += (K_ATTN_LAYER + (0 if fused else K_MLA_EXTRA)) * n_mla
 
-    n_ssm = counts.get("ssm", 0)
+    n_ssm = counts.get("ssm", 0) + counts.get("ssm_mlp", 0)
     if n_ssm:
         hs, p, n = cfg.ssm_heads, (cfg.ssm_expand * d) // cfg.ssm_heads, cfg.ssm_state
         q = cfg.ssm_chunk

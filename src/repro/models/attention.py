@@ -46,6 +46,12 @@ def _attn_scale(cfg) -> float:
     return cfg.attn_scale if cfg.attn_scale else 1.0 / np.sqrt(cfg.head_dim)
 
 
+def _rope(x, positions, cfg):
+    """Rotary positions on q or k rows; a NoPE config (``use_rope`` off)
+    leaves them as they are and adds no op."""
+    return apply_rope(x, positions, cfg.rope_theta) if cfg.use_rope else x
+
+
 def _grouped_scores(q, k, scale, softcap):
     """q: (B,S,H,hd), k: (B,L,KV,hd) -> scores (B,KV,G,S,L) fp32."""
     b, s, h, hd = q.shape
@@ -95,8 +101,8 @@ def self_attention_prefill(
         q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
         k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
         v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = _rope(q, positions, cfg)
+        k = _rope(k, positions, cfg)
 
         window = 0 if is_global else cfg.sliding_window
         ctx = attention_prefill_auto(
@@ -145,8 +151,8 @@ def self_attention_prefill_suffix(
         q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
         k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
         v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = _rope(q, positions, cfg)
+        k = _rope(k, positions, cfg)
 
     off = prefix_len[0]
     with jax.named_scope("kv_write"):
@@ -206,12 +212,21 @@ def _write_at_lengths(buf: jax.Array, new: jax.Array, lengths: jax.Array) -> jax
     """Per-example cache write at ragged positions, every layer at once:
     buf (n_units, B, L, ...), new (n_units, B, 1, ...), lengths (B,).
 
-    One scatter of ``n_units * B`` rows, in place when ``buf`` is donated,
-    and correct under any sharding. A slot at ``lengths == L`` writes
-    nothing: the scatter drops out-of-range rows.
+    A loop of one dynamic-update-slice per slot, each holding every layer's
+    row, which XLA writes in place when ``buf`` is donated, in any device
+    layout (a scatter into a leaf narrower than 128 lanes, whose sequence
+    axis the device lays out minor, copies the whole leaf first). A slot at
+    ``lengths == L`` puts back the row it would clip to.
     """
-    slots = jnp.arange(buf.shape[1])
-    return buf.at[:, slots, lengths].set(new[:, :, 0].astype(buf.dtype), mode="drop")
+    n_units, l, tail = buf.shape[0], buf.shape[2], buf.shape[3:]
+
+    def write(b, buf):
+        at = (0, b, jnp.minimum(lengths[b], l - 1)) + (0,) * len(tail)
+        old = jax.lax.dynamic_slice(buf, at, (n_units, 1, 1) + tail)
+        row = jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1).astype(buf.dtype)
+        return jax.lax.dynamic_update_slice(buf, jnp.where(lengths[b] < l, row, old), at)
+
+    return jax.lax.fori_loop(0, buf.shape[1], write, buf)
 
 
 @jax.named_scope("attn")
@@ -227,13 +242,14 @@ def _with_row_at_lengths(buf: jax.Array, new: jax.Array, lengths: jax.Array) -> 
 
 @jax.named_scope("attn")
 def _decode_qkv(params, x, lengths, cfg):
-    """Shared decode-step projections: rope'd q and new-token k/v rows."""
+    """Shared decode-step projections: q and new-token k/v rows, q and k
+    rope'd unless the config is NoPE."""
     positions = lengths[:, None]     # new token's position
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     k_new = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
     v_new = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k_new = apply_rope(k_new, positions, cfg.rope_theta)
+    q = _rope(q, positions, cfg)
+    k_new = _rope(k_new, positions, cfg)
     return q, k_new, v_new
 
 

@@ -26,6 +26,7 @@ BLOCK_KINDS = (
     "mla_moe",       # MLA + MoE MLP
     "cross_attn",    # cross-attention to encoder states + MLP
     "ssm",           # Mamba2 / SSD block
+    "ssm_mlp",       # Mamba2 / SSD mixer + MLP (granite-4.0-h)
     "gdn",           # gated-deltanet block
     "shared_attn",   # self-attention with SHARED (non-stacked) params
 )
@@ -67,6 +68,7 @@ class ModelConfig:
     attn_softcap: float = 0.0        # 0 = disabled (gemma2: 50.0)
     final_softcap: float = 0.0       # gemma2: 30.0
     attn_scale: Optional[float] = None   # override 1/sqrt(head_dim)
+    use_rope: bool = True            # False = NoPE: no rotary on q/k (granite-4.0-h)
 
     # --- MLA (DeepSeek-V2) ---------------------------------------------------
     kv_lora_rank: int = 0
@@ -101,6 +103,10 @@ class ModelConfig:
     eos_token_id: int = 0                # serving stops a request on this id
     n_media_tokens: int = 0              # vlm: encoder states per request
     embed_scale: bool = False            # gemma multiplies embeds by sqrt(d)
+    # muP multipliers (granite): 1.0 adds no op to the programs
+    embedding_multiplier: float = 1.0    # embeddings times this
+    residual_multiplier: float = 1.0     # each branch times this before its residual add
+    logits_scaling: float = 1.0          # logits divided by this
     # --- norm / numerics --------------------------------------------------------
     rms_eps: float = 1e-6
     param_dtype: str = "bfloat16"
@@ -139,7 +145,7 @@ class ModelConfig:
     def sub_quadratic(self) -> bool:
         """Eligible for long_500k decode: SSM / linear-attn / hybrid."""
         kinds = set(self.block_kinds_flat())
-        if not kinds & {"ssm", "gdn"}:
+        if not kinds & {"ssm", "ssm_mlp", "gdn"}:
             return False
         return True
 
@@ -247,6 +253,8 @@ class ModelConfig:
             return self._mla_params() + self._moe_params(active_only) + norms
         if kind == "ssm":
             return self._ssm_params() + d
+        if kind == "ssm_mlp":
+            return self._ssm_params() + self._mlp_params(self.d_ff) + norms
         if kind == "gdn":
             return self._gdn_params() + self._mlp_params(self.d_ff) + norms
         raise ValueError(kind)
@@ -278,7 +286,7 @@ def recurrent_state_bytes(cfg: ModelConfig, dtype_bytes: int = 2,
     compute-light DVFS class)."""
     total = 0
     for kind in cfg.block_kinds_flat():
-        if kind == "ssm":
+        if kind in ("ssm", "ssm_mlp"):
             d_inner = cfg.ssm_expand * cfg.d_model
             p = d_inner // cfg.ssm_heads
             conv_dim = d_inner + 2 * cfg.ssm_groups * cfg.ssm_state

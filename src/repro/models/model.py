@@ -43,6 +43,7 @@ from repro.models.layers import (
     init_rmsnorm,
     mlp,
     rmsnorm,
+    softcap_logits,
     unembed,
 )
 
@@ -91,6 +92,13 @@ def _init_block(kind: str, cfg: ModelConfig, key, dtype) -> Dict:
         return {
             "norm1": init_rmsnorm(d, dtype),
             "ssm": ssm_mod.init_ssm(keys[0], cfg, dtype),
+        }
+    if kind == "ssm_mlp":
+        return {
+            "norm1": init_rmsnorm(d, dtype),
+            "ssm": ssm_mod.init_ssm(keys[0], cfg, dtype),
+            "norm2": init_rmsnorm(d, dtype),
+            "mlp": init_mlp(keys[1], d, cfg.d_ff, cfg.mlp_type, dtype),
         }
     if kind == "gdn":
         return {
@@ -162,7 +170,7 @@ def _block_cache(kind: str, cfg: ModelConfig, batch: int, max_len: int):
             "ckv": jnp.zeros((batch, max_len, cfg.kv_lora_rank), cd),
             "kr": jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), cd),
         }
-    if kind == "ssm":
+    if kind in ("ssm", "ssm_mlp"):
         d_inner, heads, p, n, g, conv_dim = ssm_mod._dims(cfg)
         return {
             "ssm": jnp.zeros((batch, heads, p, n), jnp.float32),
@@ -197,6 +205,9 @@ def abstract_cache(cfg: ModelConfig, batch: int, max_len: int):
 # O(1)-state kinds (ssm/gdn) and the fixed encoder cache (cross_attn) stay
 # slot-indexed dense even in a paged cache.
 PAGED_KINDS = ("attn", "attn_global", "shared_attn", "mla", "mla_moe")
+# Block kinds whose O(1) state a decode step replaces whole: the decode scan
+# carries their stacked state and writes each unit's in place.
+STATE_KINDS = ("ssm", "ssm_mlp", "gdn")
 
 
 def _block_paged_cache(kind: str, cfg: ModelConfig, batch: int, n_pages: int,
@@ -246,6 +257,18 @@ def paged_layout(cfg: ModelConfig) -> Dict:
 
 
 # ------------------------------------------------------------------ forward
+def _scaled(x: jax.Array, multiplier: float) -> jax.Array:
+    """``x * multiplier`` rounded once to ``x``'s dtype; no op at 1.0."""
+    if multiplier == 1.0:
+        return x
+    return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
+
+
+def _residual(cfg: ModelConfig, x: jax.Array, branch: jax.Array) -> jax.Array:
+    """The residual add of one branch, scaled by ``residual_multiplier``."""
+    return x + _scaled(branch, cfg.residual_multiplier)
+
+
 def _block_apply(
     kind: str,
     bp: Dict,
@@ -295,9 +318,9 @@ def _block_apply(
                 bp["attn"], h, cfg, is_global=is_global,
                 cache=cache if mode == "prefill" else None,
             )
-        x = x + a_out
+        x = _residual(cfg, x, a_out)
         h = rmsnorm(bp["norm2"], x, cfg.rms_eps)
-        x = x + mlp(bp["mlp"], h, cfg.mlp_type)
+        x = _residual(cfg, x, mlp(bp["mlp"], h, cfg.mlp_type))
         return x, new_cache
 
     if kind_eff == "cross_attn":
@@ -315,9 +338,9 @@ def _block_apply(
             enc_cache = cache
             new_cache = None
         a_out = attn.cross_attention_apply(bp["xattn"], h, enc_cache, cfg)
-        x = x + a_out
+        x = _residual(cfg, x, a_out)
         h = rmsnorm(bp["norm2"], x, cfg.rms_eps)
-        x = x + mlp(bp["mlp"], h, cfg.mlp_type)
+        x = _residual(cfg, x, mlp(bp["mlp"], h, cfg.mlp_type))
         return x, new_cache
 
     if kind_eff in ("mla", "mla_moe"):
@@ -336,24 +359,30 @@ def _block_apply(
                 cache=cache if mode == "prefill" else None,
                 absorb=True,
             )
-        x = x + a_out
+        x = _residual(cfg, x, a_out)
         h = rmsnorm(bp["norm2"], x, cfg.rms_eps)
         if kind_eff == "mla_moe":
             m_out, _aux = moe_mod.moe_mlp(bp["moe"], h, cfg)
         else:
             m_out = mlp(bp["mlp"], h, cfg.mlp_type)
-        x = x + m_out
+        x = _residual(cfg, x, m_out)
         return x, new_cache
 
-    if kind_eff == "ssm":
+    if kind_eff in ("ssm", "ssm_mlp"):
         h = rmsnorm(bp["norm1"], x, cfg.rms_eps)
-        if mode == "decode":
-            s_out, new_cache = ssm_mod.ssm_decode(bp["ssm"], h, cache, cfg)
-        else:
-            s_out, new_cache = ssm_mod.ssm_prefill(
-                bp["ssm"], h, cfg, cache=cache if mode == "prefill" else None
-            )
-        return x + s_out, new_cache
+        with jax.named_scope("ssm"):
+            if mode == "decode":
+                s_out, new_cache = ssm_mod.ssm_decode(bp["ssm"], h, cache, cfg)
+            else:
+                s_out, new_cache = ssm_mod.ssm_prefill(
+                    bp["ssm"], h, cfg, cache=cache if mode == "prefill" else None,
+                    lengths=lengths if mode == "prefill" else None,
+                )
+        x = _residual(cfg, x, s_out)
+        if kind_eff == "ssm_mlp":
+            h = rmsnorm(bp["norm2"], x, cfg.rms_eps)
+            x = _residual(cfg, x, mlp(bp["mlp"], h, cfg.mlp_type))
+        return x, new_cache
 
     if kind_eff == "gdn":
         h = rmsnorm(bp["norm1"], x, cfg.rms_eps)
@@ -363,12 +392,34 @@ def _block_apply(
             g_out, new_cache = gdn_mod.gdn_prefill(
                 bp["gdn"], h, cfg, cache=cache if mode == "prefill" else None
             )
-        x = x + g_out
+        x = _residual(cfg, x, g_out)
         h = rmsnorm(bp["norm2"], x, cfg.rms_eps)
-        x = x + mlp(bp["mlp"], h, cfg.mlp_type)
+        x = _residual(cfg, x, mlp(bp["mlp"], h, cfg.mlp_type))
         return x, new_cache
 
     raise ValueError(kind)
+
+
+def _state_scope(kind: str):
+    """The scopes of a stacked state's read and write: the block's mixer,
+    then ``kv_write``, so that every pass over the state is under both."""
+    scope = "ssm" if kind in ("ssm", "ssm_mlp") else kind
+    return jax.named_scope(scope), jax.named_scope("kv_write")
+
+
+def _state_read(kind: str, stack: Dict, unit: jax.Array) -> Dict:
+    """Unit ``unit``'s state out of its block's stacked state."""
+    outer, inner = _state_scope(kind)
+    with outer, inner:
+        return jax.tree.map(lambda a: a[unit], stack)
+
+
+def _state_write(kind: str, stack: Dict, new: Dict, unit: jax.Array) -> Dict:
+    """Unit ``unit``'s new state into its block's stacked state (leaves
+    ``(n_units, ...)``), carried by the decode scan and so written in place."""
+    outer, inner = _state_scope(kind)
+    with outer, inner:
+        return jax.tree.map(lambda a, n: a.at[unit].set(n.astype(a.dtype)), stack, new)
 
 
 def _decode_write(kind: str, cache: Dict, out: Dict, lengths: jax.Array,
@@ -377,7 +428,8 @@ def _decode_write(kind: str, cache: Dict, out: Dict, lengths: jax.Array,
     """One block's stacked decode cache (leaves ``(n_units, ...)``) after a
     step, from what its units returned: per-token caches get every layer's
     new row written in place, at ``lengths`` or through the block table;
-    ssm/gdn states are replaced whole; cross-attention's stays as it is."""
+    ssm/gdn states come whole (written in place by the scan, or stacked by
+    the unrolled lowering); cross-attention's stays as it is."""
     if kind in PAGED_KINDS:
         if block_tables is None:
             return {n: attn._write_at_lengths(cache[n], out[n], lengths) for n in cache}
@@ -400,30 +452,48 @@ def _run_stages(
     prefix_len: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Dict]]:
     """Scan each stage's units. Train and prefill stream the stacked cache
-    through the scan (``xs`` in, ``ys`` out). Decode only reads it inside
-    the scan: the units' new rows leave as a small ``ys`` and are written
-    once, in place, afterwards (``_decode_write``), so the cache is never
-    copied whole."""
+    through the scan (``xs`` in, ``ys`` out). Decode only reads a per-token
+    cache inside the scan: the units' new rows leave as a small ``ys`` and
+    are written once, in place, afterwards (``_decode_write``); the stacked
+    state of ``STATE_KINDS`` rides in the scan's carry and each unit reads
+    its own and writes it back in place (``_state_read``, ``_state_write``).
+    So no cache is copied whole.
+    ``lengths`` in prefill: the prompts' true lengths (recurrent blocks keep
+    padding out of their state)."""
     shared = params.get("shared_block")
     new_stage_caches = []
     for si, stage in enumerate(cfg.stages):
         sp = params["stages"][si]
         sc = cache["stages"][si] if cache is not None else None
+        carried = tuple(f"b{i}" for i, kind in enumerate(stage.unit)
+                        if kind in STATE_KINDS) \
+            if mode == "decode" and sc is not None and not unroll_enabled() else ()
 
-        def unit_fn(carry_x, xs, _stage=stage):
-            up, uc = xs
+        def unit_fn(carry, xs, _stage=stage, _carried=carried):
+            if _carried:        # ((x, stacked states), (params, caches, unit))
+                (carry_x, states), (up, uc, u) = carry, xs
+                states = dict(states)
+            else:
+                carry_x, (up, uc) = carry, xs
             new_uc = {}
             for i, kind in enumerate(_stage.unit):
-                bc = uc[f"b{i}"] if uc is not None else None
+                b = f"b{i}"
+                if b in _carried:
+                    bc = _state_read(kind, states[b], u)
+                else:
+                    bc = uc[b] if uc is not None else None
                 carry_x, nbc = _block_apply(
-                    kind, up[f"b{i}"], carry_x, cfg, mode, bc, lengths, shared,
+                    kind, up[b], carry_x, cfg, mode, bc, lengths, shared,
                     enc_states, block_tables, prefix_len,
                 )
-                new_uc[f"b{i}"] = nbc if nbc is not None else {}
+                if b in _carried:
+                    states[b] = _state_write(kind, states[b], nbc, u)
+                else:
+                    new_uc[b] = nbc if nbc is not None else {}
             # keep activations batch-sharded across unit boundaries (no-op
             # unless the launch layer configured batch axes)
             carry_x = constrain_batch(carry_x)
-            return carry_x, new_uc
+            return ((carry_x, states) if _carried else carry_x), new_uc
 
         body = jax.checkpoint(unit_fn) if (remat and mode == "train") else unit_fn
         if unroll_enabled():
@@ -435,6 +505,12 @@ def _run_stages(
                 x, nuc = body(x, (up_u, uc_u))
                 new_units.append(nuc)
             new_sc = jax.tree.map(lambda *ls: jnp.stack(ls), *new_units)
+        elif carried:
+            states = {b: sc[b] for b in carried}
+            rows = {b: c for b, c in sc.items() if b not in states}
+            (x, states), new_sc = jax.lax.scan(
+                body, (x, states), (sp, rows, jnp.arange(stage.n_units)))
+            new_sc = {**new_sc, **states}
         elif sc is not None:
             x, new_sc = jax.lax.scan(body, x, (sp, sc))
         else:
@@ -453,7 +529,8 @@ def _embed_inputs(params, cfg: ModelConfig, inputs):
     cd = _cdtype(cfg)
     if cfg.input_is_embeddings:
         return inputs.astype(cd)
-    return embed(params["embed"], inputs, cfg.embed_scale, cfg.d_model, cd)
+    x = embed(params["embed"], inputs, cfg.embed_scale, cfg.d_model, cd)
+    return _scaled(x, cfg.embedding_multiplier)
 
 
 def forward(
@@ -477,7 +554,10 @@ def forward(
 
 @jax.named_scope("lm_head")
 def logits(params: Dict, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:
-    return unembed(params["embed"], hidden, cfg.final_softcap)
+    if cfg.logits_scaling == 1.0:
+        return unembed(params["embed"], hidden, cfg.final_softcap)
+    return softcap_logits(unembed(params["embed"], hidden) / cfg.logits_scaling,
+                          cfg.final_softcap)
 
 
 def prefill(
@@ -491,10 +571,12 @@ def prefill(
 ) -> Tuple[jax.Array, Dict, jax.Array]:
     """Process the prompt, fill caches, return last-valid-token logits."""
     b, s = inputs.shape[0], inputs.shape[1]
+    true_lengths = prompt_lengths
     if prompt_lengths is None:
         prompt_lengths = jnp.full((b,), s, dtype=jnp.int32)
     x = _embed_inputs(params, cfg, inputs)
-    x, new_cache = _run_stages(params, cfg, x, "prefill", cache, None, enc_states, False)
+    x, new_cache = _run_stages(params, cfg, x, "prefill", cache, true_lengths, enc_states,
+                               False)
     x = rmsnorm(params["final_norm"], x, cfg.rms_eps)
     last = jnp.take_along_axis(x, (prompt_lengths - 1)[:, None, None], axis=1)[:, 0]
     return logits(params, cfg, last[:, None])[:, 0], new_cache, prompt_lengths

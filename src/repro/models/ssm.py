@@ -57,8 +57,11 @@ def _split_proj(cfg, proj):
     return z, xs, b, c, dt
 
 
-def _causal_conv(u: jax.Array, w: jax.Array, b: jax.Array, state: Optional[jax.Array]):
-    """Depthwise causal conv1d. u: (B,S,C), w: (K,C). Returns (y, new_state)."""
+def _causal_conv(u: jax.Array, w: jax.Array, b: jax.Array, state: Optional[jax.Array],
+                 lengths: Optional[jax.Array] = None):
+    """Depthwise causal conv1d. u: (B,S,C), w: (K,C). Returns (y, new_state):
+    the window of the last K-1 inputs, or with ``lengths`` (B,) of the last
+    K-1 before each row's true length (a bucket-padded prompt)."""
     k = w.shape[0]
     if state is None:
         pad = jnp.zeros((u.shape[0], k - 1, u.shape[2]), dtype=u.dtype)
@@ -67,7 +70,13 @@ def _causal_conv(u: jax.Array, w: jax.Array, b: jax.Array, state: Optional[jax.A
     full = jnp.concatenate([pad, u], axis=1)          # (B, S+K-1, C)
     # window sum: y_t = sum_j w_j * full[t+j]
     y = sum(full[:, j : j + u.shape[1], :] * w[j] for j in range(k)) + b
-    new_state = full[:, -(k - 1) :, :] if k > 1 else None
+    if k == 1:
+        new_state = None
+    elif lengths is None:
+        new_state = full[:, -(k - 1) :, :]
+    else:   # input t sits at full[t + K-1]: the window is full[n : n+K-1]
+        new_state = jax.vmap(
+            lambda f, n: jax.lax.dynamic_slice_in_dim(f, n, k - 1, axis=0))(full, lengths)
     return jax.nn.silu(y), new_state
 
 
@@ -173,16 +182,25 @@ def ssm_prefill(
     cfg,
     *,
     cache: Optional[Dict] = None,
+    lengths: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Dict]]:
+    """Prefill over ``x`` (B, S, d). ``lengths`` (B,): each row's true
+    length, past which it is padding that must not reach the state or the
+    conv window (None: every row is S long)."""
     bsz, s, _ = x.shape
     d_inner, heads, p, n, g, conv_dim = _dims(cfg)
     proj = x @ params["w_in"]
     z, xs, b, c, dtp = _split_proj(cfg, proj)
     conv_in = jnp.concatenate([xs, b, c], axis=-1)
-    conv_out, conv_state = _causal_conv(conv_in, params["conv_w"], params["conv_b"], None)
+    conv_out, conv_state = _causal_conv(conv_in, params["conv_w"], params["conv_b"], None,
+                                        lengths)
     xs, b, c = jnp.split(conv_out, [d_inner, d_inner + g * n], axis=-1)
 
     dtv = jax.nn.softplus(dtp.astype(jnp.float32) + params["dt_bias"])
+    if lengths is not None:
+        # past the true length dt is 0: the state decays by exp(0) = 1 and
+        # takes no input, so the final state is the last real token's
+        dtv = jnp.where(jnp.arange(s)[None, :, None] < lengths[:, None, None], dtv, 0.0)
     a = -jnp.exp(params["a_log"])
     y, final_state = ssd_chunked(
         xs.reshape(bsz, s, heads, p),

@@ -194,6 +194,7 @@ class Replica:
         kv_blocks: Optional[int] = None,
         prefix_sharing: bool = False,
         device: Any = None,
+        overlap_decode: bool = False,
     ):
         self.cfg = cfg
         self.name = name
@@ -224,6 +225,7 @@ class Replica:
             meter_interval_s=meter_interval_s,
             paged=paged, kv_block_size=kv_block_size, kv_blocks=kv_blocks,
             prefix_sharing=prefix_sharing, device=device,
+            overlap=overlap_decode,
         )
         self.controller = controller
         self.scheduler = Scheduler(prefill_chunk_tokens)
@@ -298,6 +300,7 @@ class Replica:
             kv_blocks=spec.decode.kv_blocks,
             prefix_sharing=spec.decode.prefix_sharing,
             device=device,
+            overlap_decode=spec.decode.overlap,
         )
 
     # ------------------------------------------------------------------ api

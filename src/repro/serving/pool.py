@@ -219,6 +219,12 @@ def decode_jit_for(cfg: ModelConfig, paged: bool = False):
         decode_impl_for(cfg), donate_argnums=(2,)))
 
 
+def _carry_tokens_impl(prev, host, carried):
+    """A step's input tokens when the step before it is still in flight:
+    slots it serves take its device output, fresh placements the host's."""
+    return jnp.where(carried, prev, host)
+
+
 def params_on_device(params: Any, device: Any) -> Any:
     """The copy of ``params`` that ``device`` holds, without copying: each
     leaf must be committed to ``device`` or replicated over devices that
@@ -594,6 +600,7 @@ class Pool:
         kv_blocks: Optional[int] = None,   # default: dense-equivalent budget
         prefix_sharing: bool = False,
         device: Any = None,
+        overlap: bool = False,
     ):
         self.cfg = cfg
         # device binding (multi-device hosts): a bound pool keeps its cache
@@ -616,6 +623,14 @@ class Pool:
         # the modelled duration of each phase call (needs an operating
         # point, i.e. a ClockController); metering goes synchronous.
         self.virtual = isinstance(clock, VirtualClock)
+        # overlap: ``decode_once`` dispatches step N+1 before it reads step
+        # N's tokens, so the host's accounting, admission and dispatch run
+        # while the device computes. Dense wall-clock pools only: modelled
+        # time has nothing to hide, and a paged step's block tables need
+        # the lengths the host has not read yet.
+        self.overlap = overlap and not self.virtual and not paged
+        self._inflight: Optional[dict] = None
+        self._settled_s = float("-inf")
         self.stats = PhaseStats()
         self.eos_token_id = cfg.eos_token_id
 
@@ -716,6 +731,8 @@ class Pool:
             lambda: jax.jit(self._prefill_impl, static_argnames=("bucket",)))
         self._jit_decode = decode_jit_for(cfg)
         self._jit_decode_paged = decode_jit_for(cfg, paged=True)
+        self._jit_carry_tokens = _cached(
+            ("carry_tokens_jit",), lambda: jax.jit(_carry_tokens_impl))
         self._jit_scatter = _cached(
             ("scatter_jit",), lambda: jax.jit(_scatter_impl, donate_argnums=(0,)))
         if paged:
@@ -997,6 +1014,8 @@ class Pool:
                 make, out_shardings=jax.sharding.SingleDeviceSharding(self.device))()
             self.lengths = jnp.zeros((self.max_batch,), jnp.int32)
             self.cur_token = jnp.zeros((self.max_batch,), jnp.int32)
+            if self.device is not None:
+                self.cur_token = jax.device_put(self.cur_token, self.device)
 
     def active_mask(self) -> np.ndarray:
         return np.array([r is not None for r in self.slot_req])
@@ -1463,7 +1482,8 @@ class Pool:
     def _req_eos(self, req: Request) -> int:
         return self.eos_token_id if req.eos_token_id is None else req.eos_token_id
 
-    def _decode_begin(self, *, keep_view: bool = False) -> Optional[dict]:
+    def _decode_begin(self, *, keep_view: bool = False,
+                      after: Optional[dict] = None) -> Optional[dict]:
         """Host-side first half of ``decode_once``: block-table growth,
         active mask, RNG split, and the jitted-call argument tuple. Returns
         ``None`` when no slot is live. ``decode_once`` composes this with
@@ -1476,13 +1496,19 @@ class Pool:
         so serial and tuple-fused consumers see a concrete pytree in
         ``args``; the batched engine path passes ``keep_view=True`` and
         resolves the view itself (either reusing the bank's stacked tree
-        directly or gathering rows inside its own program)."""
+        directly or gathering rows inside its own program).
+
+        ``after`` is the step still in flight on an overlapping pool: slots
+        it gives their last token sit this step out, and the slots it
+        serves take their input token from its device output."""
         with span("decode.prepare"):
             if self.paged and any(r is not None for r in self.slot_req):
                 self._grow_tables()
                 if self._prefix is not None:
                     self._cow_guard()
             active = self.active_mask()
+            if self.overlap:
+                active &= ~self._finishing(after)
             if not active.any():
                 return None
             if not keep_view:
@@ -1495,6 +1521,9 @@ class Pool:
             # per-array device_put is paid here. Copies because the mirrors
             # mutate between this dispatch and the next placement.
             toks = self._host_cur_token.copy()
+            if self.overlap:
+                toks = self._jit_carry_tokens(self.cur_token, toks,
+                                              self._carried(after))
             lengths = self._host_lengths.astype(np.int32)
             temps = self._slot_temp.copy()
             if self.paged:
@@ -1503,13 +1532,35 @@ class Pool:
             else:
                 args = (self.params, toks, self.cache, lengths,
                         active, sub, temps)
-            return {"active": active, "t0": t0, "args": args}
+            pre = {"active": active, "t0": t0, "args": args}
+            if self.overlap:
+                pre["reqs"] = [r if a else None
+                               for r, a in zip(self.slot_req, active)]
+            return pre
+
+    def _finishing(self, inflight: Optional[dict]) -> np.ndarray:
+        """Slots whose request the in-flight step gives its last token."""
+        out = np.zeros(self.max_batch, bool)
+        for i, req in enumerate(inflight["reqs"] if inflight else ()):
+            out[i] = req is not None and len(req.output) + 1 >= req.max_new_tokens
+        return out
+
+    def _carried(self, inflight: Optional[dict]) -> np.ndarray:
+        """Slots whose next input token the in-flight step computes: it
+        serves them and no placement has replaced their request since."""
+        out = np.zeros(self.max_batch, bool)
+        for i, req in enumerate(inflight["reqs"] if inflight else ()):
+            out[i] = req is not None and self.slot_req[i] is req
+        return out
 
     def decode_once(self) -> List[Request]:
         """One jitted decode step over all slots; returns finished requests.
 
         Paged pools grow/evict block tables first, then account the step's
-        traffic block-accurately and derive decode joules from it."""
+        traffic block-accurately and derive decode joules from it.
+        Overlapping pools return what the step before this one finished."""
+        if self.overlap:
+            return self._decode_overlapped()
         pre = self._decode_begin()
         if pre is None:
             return []
@@ -1519,10 +1570,34 @@ class Pool:
         self.jit_dispatches += 1
         return self._decode_finish(pre, next_tok, cache, lengths)
 
+    def _decode_overlapped(self) -> List[Request]:
+        """Dispatch this step, then account the one still in flight. The
+        new step is dispatched on the in-flight step's donated cache and
+        device tokens, so nothing waits for the host; the host mirrors
+        advance at dispatch. A request that stops on its EOS token is
+        served once more by the step already dispatched; that token is
+        dropped."""
+        prev, self._inflight = self._inflight, None
+        # with no slot to run, the call only accounts: no prepare span
+        live = self.active_mask() & ~self._finishing(prev)
+        pre = self._decode_begin(after=prev) if live.any() else None
+        if pre is not None:
+            with span("decode.dispatch"):
+                next_tok, self.cache, self.lengths = self._jit_decode(*pre["args"])
+            self.jit_dispatches += 1
+            self.cur_token = next_tok
+            self._host_lengths[pre["active"]] += 1
+            pre["out"] = next_tok
+            self._inflight = pre
+        if prev is None:
+            return []
+        return self._decode_finish(prev, prev["out"], None, None)
+
     def _decode_finish(self, pre: dict, next_tok, cache, lengths) -> List[Request]:
-        """Second half of ``decode_once``: adopt the jitted step's outputs,
-        advance the (virtual) clock by the modelled step duration, and do
-        the per-slot token/energy/EOS accounting."""
+        """Second half of ``decode_once``: adopt the jitted step's outputs
+        (an overlapped step's were adopted at dispatch), advance the
+        (virtual) clock by the modelled step duration, and do the per-slot
+        token/energy/EOS accounting."""
         with span("decode.sync"):
             next_np = np.asarray(next_tok)
         with span("decode.account"):
@@ -1530,22 +1605,33 @@ class Pool:
 
     def _decode_account(self, pre: dict, next_np: np.ndarray, next_tok, cache,
                         lengths) -> List[Request]:
-        """``_decode_finish`` once the step's tokens are on the host."""
-        self.cache = cache
-        self.lengths = lengths
-        active = pre["active"]
+        """``_decode_finish`` once the step's tokens are on the host. An
+        overlapped step (``reqs`` in ``pre``) was dispatched after it: the
+        pool already holds the later step's cache, tokens and lengths, and
+        only the requests the step served, and have not finished, take its
+        tokens."""
+        overlapped = "reqs" in pre
+        reqs = pre["reqs"] if overlapped else self.slot_req
         t0 = pre["t0"]
+        if overlapped:
+            # steps in flight overlap: each one's time starts where the
+            # last one's accounting ended
+            t0 = max(t0, self._settled_s)
+        else:
+            self.cache = cache
+            self.lengths = lengths
         finished: List[Request] = []
         if self.virtual and self.op is not None:
             # the modelled step duration at the live operating point IS the
             # virtual-time cost of this decode step
             self.advance_time(self.op.profile.t_total)
         dt = self.clock() - t0
-        n_active = int(active.sum())
-        self.cur_token = next_tok
-        # keep the host mirror in lock-step with the device vector; copy
-        # because placements mutate it in place before the next step
-        self._host_cur_token = np.array(next_np, dtype=np.int32)
+        n_active = sum(r is not None and not r.done for r in reqs)
+        if not overlapped:
+            self.cur_token = next_tok
+            # keep the host mirror in lock-step with the device vector; copy
+            # because placements mutate it in place before the next step
+            self._host_cur_token = np.array(next_np, dtype=np.int32)
 
         # ---- energy + traffic attribution for this step ------------------
         mj = self._mj_per_token()
@@ -1579,10 +1665,12 @@ class Pool:
         self.stats.merge_decode(n_active, dt, step_j, read_total, write_total)
 
         now = self.clock()
-        for i, req in enumerate(self.slot_req):
-            if req is None:
+        self._settled_s = now
+        for i, req in enumerate(reqs):
+            if req is None or req.done:
                 continue
-            self._host_lengths[i] += 1
+            if not overlapped:
+                self._host_lengths[i] += 1
             req.decode_s += dt / max(n_active, 1)
             req.decode_j += per_req_j.get(i, mj / 1e3)
             tok = int(next_np[i])

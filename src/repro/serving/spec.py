@@ -50,6 +50,10 @@ class PoolSpec:
     # only, requires paged — default off so existing specs replay
     # byte-identically
     prefix_sharing: bool = False
+    # a replica's dense decode pool on a wall clock dispatches each decode
+    # step before it reads the last one's tokens (``Pool(overlap=True)``);
+    # ignored on virtual clocks and by paged pools
+    overlap: bool = True
 
     def __post_init__(self):
         _require(self.batch >= 1, f"PoolSpec.batch must be >= 1, got {self.batch}")

@@ -334,3 +334,101 @@ class TestQueueDelaySummary:
         lat = summarize_latency(done)
         assert lat.p99_queue_s >= 0.0
         assert lat.p95_e2e_s >= lat.p50_e2e_s > 0.0
+
+
+class TestOverlappedDecode:
+    """A wall-clock replica's dense decode pool dispatches step N+1 before
+    it reads step N's tokens (``PoolSpec.overlap``): the same tokens as the
+    step-synchronous loop, with the host's work hidden behind the device's."""
+
+    @staticmethod
+    def _serve(params, arch, overlap, eos=None, batch=3):
+        import time
+
+        spec = FleetSpec(replicas=(ReplicaSpec(
+            name="r0", arch=arch, clock=ClockSpec(mode="lock"),
+            decode=PoolSpec(batch=batch, overlap=overlap), max_seq_len=64,
+            prefill_chunk_tokens=64),))
+        fleet = _fleet(spec, {arch: params}, clock=time.perf_counter)
+        rng = np.random.default_rng(5)
+        reqs = [fleet.submit(rng.integers(1, 200, size=int(rng.integers(5, 30))),
+                             int(rng.integers(2, 12)), eos_token_id=eos)
+                for _ in range(7)]
+        while fleet.busy():
+            fleet.step()
+        return reqs, fleet.replicas[0].decode_pool
+
+    @pytest.mark.parametrize("arch", [ARCH, ALT, "granite-4.0-h-micro"])
+    @pytest.mark.parametrize("stop", ["max_new", "eos"])
+    def test_overlap_serves_the_synchronous_tokens(self, setup, arch, stop):
+        params = setup.get(arch) or init_params(reduced_config(arch),
+                                                jax.random.PRNGKey(0))
+        eos = None
+        if stop == "eos":
+            # a token the synchronous loop serves mid-stream: some requests
+            # stop on it, and the overlapped loop has already dispatched the
+            # step after, whose token for them it must drop
+            ref, _ = self._serve(params, arch, False)
+            eos = max(ref, key=lambda r: len(r.output)).output[2]
+        sync, sync_pool = self._serve(params, arch, False, eos)
+        ovl, ovl_pool = self._serve(params, arch, True, eos)
+        assert not sync_pool.overlap and ovl_pool.overlap
+        assert all(r.done for r in ovl)
+        assert [r.output for r in ovl] == [r.output for r in sync]
+        if stop == "eos":
+            assert any(len(r.output) < r.max_new_tokens for r in sync)
+        assert ovl_pool.stats.decode_tokens == sync_pool.stats.decode_tokens
+        assert ovl_pool.occupancy() == 0
+
+    def test_the_next_step_is_dispatched_before_the_tokens_are_read(self, setup):
+        from repro.serving.pool import Pool
+
+        events = []
+        orig_finish = Pool._decode_finish
+
+        def finish(pool, pre, *a):
+            events.append("read")
+            return orig_finish(pool, pre, *a)
+
+        Pool._decode_finish = finish
+        try:
+            import time
+
+            spec = FleetSpec(replicas=(_rspec("a", batch=2),))
+            fleet = _fleet(spec, setup, clock=time.perf_counter)
+            pool = fleet.replicas[0].decode_pool
+            step = pool._jit_decode
+
+            def dispatch(*a):
+                events.append("dispatch")
+                return step(*a)
+
+            pool._jit_decode = dispatch
+            for n in (9, 7):
+                fleet.submit(np.arange(1, n, dtype=np.int32), 6)
+            while fleet.busy():
+                fleet.step()
+        finally:
+            Pool._decode_finish = orig_finish
+        # each step's tokens are read only once the next step is on its way,
+        # but for the last, which no step follows
+        assert events[:3] == ["dispatch", "dispatch", "read"]
+        assert events.count("dispatch") == events.count("read") == 5
+        assert events[-2:] == ["read", "read"]
+
+    def test_overlap_is_for_dense_wall_clock_pools(self, setup):
+        import time
+
+        from repro.serving.pool import Pool
+
+        cfg = reduced_config(ARCH)
+        kw = dict(max_batch=2, max_seq_len=64, overlap=True)
+        assert Pool(cfg, setup[ARCH], clock=time.perf_counter, **kw).overlap
+        assert not Pool(cfg, setup[ARCH], clock=VirtualClock(), **kw).overlap
+        assert not Pool(cfg, setup[ARCH], clock=time.perf_counter, paged=True,
+                        **kw).overlap
+        assert PoolSpec().overlap
+        spec = FleetSpec(replicas=(_rspec("a"),))
+        assert FleetSpec.from_json(spec.to_json()) == spec
+        # a virtual fleet built from the same spec keeps the synchronous loop
+        assert not _fleet(spec, setup).replicas[0].decode_pool.overlap

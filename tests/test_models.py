@@ -52,6 +52,11 @@ CASES = {
     "hybrid": tiny([(("ssm", "ssm", "shared_attn"), 2)], family="hybrid",
                    ssm_state=16, ssm_heads=4, ssm_chunk=4, n_kv_heads=4),
     "vlm": tiny([(("attn", "cross_attn"), 2)], family="vlm", n_media_tokens=6),
+    # granite-4.0-h: Mamba2 + MLP blocks beside NoPE GQA, muP multipliers
+    "granite": tiny([(("ssm_mlp", "attn", "ssm_mlp"), 2)], family="hybrid",
+                    ssm_state=16, ssm_heads=4, ssm_chunk=4, use_rope=False,
+                    attn_scale=1 / 16, embedding_multiplier=12.0,
+                    residual_multiplier=0.22, logits_scaling=8.0),
     "audio": tiny([(("attn",), 2)], family="audio", input_is_embeddings=True),
 }
 
@@ -237,7 +242,7 @@ def test_decode_step_writes_rows_like_the_full_buffer_formula(name, case):
                                  jax.tree.map(lambda a: a[:, 0], before[f"b{i}"]))
 
 
-@pytest.mark.parametrize("name", ["gdn", "hybrid", "moe", "vlm"])   # every block kind
+@pytest.mark.parametrize("name", ["gdn", "hybrid", "moe", "vlm", "granite"])   # every block kind
 def test_unrolled_decode_matches_the_scanned_one(name):
     """The accounting lowering (units in a Python loop) writes the same
     caches as the scan; XLA may fuse the two programs apart, so values
@@ -256,6 +261,23 @@ def test_unrolled_decode_matches_the_scanned_one(name):
         set_unroll(False)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6), unrolled, scanned)
+
+
+@pytest.mark.parametrize("width", [128, 64], ids=["lanes128", "lanes64"])
+def test_row_writes_match_the_formula(width):
+    """``_write_at_lengths`` writes each slot's new row, every layer at
+    once, as the ``where`` formula does, bit for bit, for qwen3-4b's
+    128-wide heads and granite's 64-wide ones; the slot at ``lengths == L``
+    keeps its rows."""
+    from repro.models import attention
+
+    buf = jax.random.normal(jax.random.PRNGKey(1), (2, 3, L_DECODE, 2, width))
+    new = jax.random.normal(jax.random.PRNGKey(2), (2, 3, 1, 2, width))
+    lengths = jnp.array([2, L_DECODE, 0], jnp.int32)
+    got = attention._write_at_lengths(buf, new, lengths)
+    want = jax.vmap(lambda b, r: _with_row(b, r, lengths))(buf, new)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got[:, 1]), np.asarray(buf[:, 1]))
 
 
 def test_a_stale_row_write_shows_on_the_next_step(monkeypatch):
@@ -324,7 +346,7 @@ class TestMoE:
 
 def test_param_count_matches_actual_tree():
     """Analytic param_count agrees with the instantiated tree (<0.5%)."""
-    for name in ("gqa", "mla", "moe", "ssm", "gdn", "hybrid"):
+    for name in ("gqa", "mla", "moe", "ssm", "gdn", "hybrid", "granite"):
         cfg = CASES[name]
         params = init_params(cfg, jax.random.PRNGKey(0))
         actual = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
@@ -332,3 +354,58 @@ def test_param_count_matches_actual_tree():
         assert abs(actual - predicted) / actual < 0.005, (
             f"{name}: actual {actual} vs predicted {predicted}"
         )
+
+
+def test_param_count_matches_the_granite_tree():
+    """granite-4.0-h-micro at its published widths: the analytic count is
+    the tree's, to the parameter (about 3.19e9)."""
+    from repro.configs import get_config
+    from repro.models import abstract_params
+
+    cfg = get_config("granite-4.0-h-micro")
+    actual = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(abstract_params(cfg)))
+    assert actual == cfg.param_count() == 3_191_396_096
+
+
+def _prefill_state(cfg, params, tokens, bucket, lengths):
+    """Prefill ``tokens`` (1, S) into a cache of ``bucket`` rows: logits,
+    cache, and the logits of one decode step after it."""
+    lg, cache, ln = prefill(params, cfg, tokens, init_cache(cfg, 1, bucket),
+                            prompt_lengths=lengths)
+    nxt = jnp.argmax(lg, -1).astype(jnp.int32)
+    lg2, _, _ = decode_step(params, cfg, nxt, cache, ln)
+    return lg, cache, lg2
+
+
+@pytest.mark.parametrize("name", ["ssm", "granite"])
+def test_prefill_at_a_padded_bucket_matches_the_exact_length(name):
+    """A serving pool pads a 37-token prompt to its 64-token bucket and
+    passes the true length: the recurrent state, the conv window and the
+    next-token logits (and the next step's) are those of a prefill at
+    exactly 37 tokens. Padding rows take dt = 0 (an exact no-op) and the
+    conv window is cut at the true length, so the two agree to float32
+    rounding of sums taken in another grouping (1e-5). Without the length
+    the pad tokens reach the state, and the check fails."""
+    cfg = CASES[name]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    n, bucket = 37, 64
+    toks = jax.random.randint(jax.random.PRNGKey(11), (1, bucket), 1, cfg.vocab_size)
+    want = _prefill_state(cfg, params, toks[:, :n], bucket, None)
+    got = _prefill_state(cfg, params, toks, bucket, jnp.array([n], jnp.int32))
+    blind = _prefill_state(cfg, params, toks, bucket, None)   # the pad reaches the state
+
+    def close(a, b):
+        return np.allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+
+    for stage, cw, cg, cb in zip(cfg.stages, want[1]["stages"], got[1]["stages"],
+                                 blind[1]["stages"]):
+        for i, kind in enumerate(stage.unit):
+            w, g = cw[f"b{i}"], cg[f"b{i}"]
+            if kind in M.PAGED_KINDS:       # rows past the prompt are never read
+                w, g = ({k: v[:, :, :n] for k, v in c.items()} for c in (w, g))
+            else:
+                assert not all(close(cb[f"b{i}"][k], w[k]) for k in w)
+            for k in w:
+                assert close(g[k], w[k]), (i, k)
+    assert close(got[0], want[0]) and close(got[2], want[2])
+    assert not close(blind[2], want[2])

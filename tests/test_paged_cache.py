@@ -236,6 +236,35 @@ class TestPagedDenseEquivalence:
             assert a.output == b.output
         assert paged.pool.allocator.used_blocks == 0  # all blocks returned
 
+    def test_hybrid_paged_pool_serves_the_dense_tokens(self):
+        """granite's reduced hybrid (Mamba2 state beside attention KV): the
+        paged pool (KV in pages, state slot-indexed) serves the dense pool's
+        tokens, and both serve what exact-length prefill and greedy decode
+        of the model give, though every prompt is padded to its bucket."""
+        cfg = reduced_config("granite-4.0-h-micro")
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        prompts = make_prompts(cfg, 4, 5, 30, seed=4)
+        assert any(len(p) not in (32, 64) for p in prompts)
+
+        def greedy(p, n):
+            lg, cache, ln = prefill(params, cfg, jnp.asarray(p[None]),
+                                    init_cache(cfg, 1, 64))
+            out = []
+            for _ in range(n):
+                out.append(int(jnp.argmax(lg[0])))
+                lg, cache, ln = decode_step(params, cfg, jnp.asarray(out[-1:]), cache, ln)
+            return out
+
+        dense = ServingEngine(cfg, params, max_batch=3, max_seq_len=64)
+        rd = [dense.submit(p, max_new_tokens=6) for p in prompts]
+        dense.run_to_completion()
+        paged = ServingEngine(cfg, params, max_batch=3, max_seq_len=64,
+                              paged=True, kv_block_size=8, kv_blocks=24)
+        rp = [paged.submit(p, max_new_tokens=6) for p in prompts]
+        paged.run_to_completion(max_steps=2000)
+        for p, a, b in zip(prompts, rd, rp):
+            assert a.output == b.output == greedy(p, 6)
+
     @staticmethod
     def _migrated(cfg, params, B=2, L_max=32, bs=8):
         """Two prefilled prompts laid into a dense cache and, page by page,

@@ -109,11 +109,16 @@ def test_barrier_steps_leave_one_decode_span_of_each_kind(params, recorder):
     for i, r in enumerate(recs):
         if r[0] in DECODE:
             assert _chain(recs, i)[1:] == ["step"]
-    # every step that decoded holds its four spans in order
+    # every step that decoded holds its spans in order: the wall-clock
+    # decode pool overlaps, so a step prepares and dispatches its own
+    # decode, then syncs and accounts the one before it
+    decoded = []
     for i, r in enumerate(recs):
         if r[0] == "step":
             kids = [k[0] for k in recs if k[3] == i and k[0] in DECODE]
-            assert kids in ([], list(DECODE))
+            assert kids in ([], list(DECODE[:2]), list(DECODE[2:]), list(DECODE))
+            decoded += [kids] if kids else []
+    assert decoded[0] == list(DECODE[:2]) and decoded[-1] == list(DECODE[2:])
 
 
 def test_the_fused_path_leaves_one_decode_span_of_each_kind(params, recorder):

@@ -150,6 +150,31 @@ def test_qwen3_decode_step_fits_one_chip(one_chip, paged):
     _writes_cache_in_place(compiled, cache, cfg.n_blocks)
 
 
+def test_granite_decode_step_fits_one_chip(one_chip):
+    """granite-4.0-h-micro's decode program at the benchmark cell's pool
+    (batch 32, max_seq_len 2048): its 2.42 GB of stacked Mamba2 state and
+    its KV cache are donated and written in place, with no second copy:
+    scratch under one layer's state at batch 32 (76 MB x 32 / 36)."""
+    from repro.serving.pool import decode_jit_for
+
+    cfg = get_config("granite-4.0-h-micro")
+    batch = 32
+    s = _shapes(one_chip)
+    on_chip = lambda tree: jax.tree.map(lambda x: s(x.shape, x.dtype), tree)
+    cache = on_chip(abstract_cache(cfg, batch, SEQ))
+    args = (on_chip(abstract_params(cfg)), s((batch,), I32), cache,
+            s((batch,), I32), s((batch,), jnp.bool_), s((2,), jnp.uint32),
+            s((batch,), F32))
+    compiled = decode_jit_for(cfg).lower(*args).compile()
+    _fits(compiled, _tree_bytes(cache))
+    state = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache)
+                if x.dtype == F32)
+    n_ssm = sum(k == "ssm_mlp" for k in cfg.block_kinds_flat())
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < state // n_ssm, f"{temp} B of scratch"
+    _writes_cache_in_place(compiled, cache, cfg.n_blocks)
+
+
 def test_qwen3_mesh_decode_fits_four_chips(topo):
     """The four-chip phase's fused decode: four replicas' banks sharded one
     row per chip under shard_map, weights replicated — compiles for a 2x2
