@@ -51,7 +51,9 @@ def run() -> list[Row]:
     k = jax.random.normal(jax.random.fold_in(key, 1), (B, L, KV, D))
     v = jax.random.normal(jax.random.fold_in(key, 2), (B, L, KV, D))
     vl = jnp.full((B,), L, jnp.int32)
-    us_k = _bench(decode_attention, q, k, v, vl, scale=0.125, block_k=128, interpret=True)
+    # the kernel reads a one-layer stack: L - 1 cached rows and the new row
+    us_k = _bench(decode_attention, q, k[:, -1], v[:, -1], k[None], v[None], jnp.int32(0),
+                  vl - 1, jnp.ones((B,), bool), scale=0.125, interpret=True)
     us_r = _bench(decode_attention_ref, q, k, v, vl, 0.125)
     csv_rows.append(["decode_attn", us_k, us_r])
     rows.append(("kernel_decode_attn", us_k, f"ref_us={us_r:.0f};interpret=True"))

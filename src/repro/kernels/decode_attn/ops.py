@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the flash-decode kernels.
 
-``gqa_decode_attention`` adapts the model's dense cache layout
-((B, L, KV, hd) + per-request lengths) to the kernel and pads L to the
-block size; ``gqa_paged_decode_attention`` takes the paged layout
+``gqa_decode_attention`` takes the model's decode layout (q and the new
+K/V rows with their query axis, the stacked (n_units, B, L, KV, hd) cache
+and the layer's index); ``gqa_paged_decode_attention`` takes the paged layout
 ((P, bs, KV, hd) pages + a per-request block table) as-is. Both compile
 for the TPU by default; CPU callers pass ``interpret=True``.
 """
@@ -12,30 +12,33 @@ import functools
 
 import jax
 
-from repro.kernels.common import clamp_block, pad_to_multiple
 from repro.kernels.decode_attn.decode_attn import decode_attention, paged_decode_attention
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "softcap", "window", "interpret"))
 def gqa_decode_attention(
     q: jax.Array,          # (B, 1, H, hd) or (B, H, hd)
-    k_cache: jax.Array,    # (B, L, KV, hd)
-    v_cache: jax.Array,    # (B, L, KV, hd)
-    valid_len: jax.Array,  # (B,)
+    k_new: jax.Array,      # (B, 1, KV, hd) or (B, KV, hd)
+    v_new: jax.Array,
+    k_cache: jax.Array,    # (n_units, B, L, KV, hd) stacked over layers
+    v_cache: jax.Array,
+    unit: jax.Array,       # () the layer to read
+    lengths: jax.Array,    # (B,)
+    active: jax.Array,     # (B,) bool
     *,
     scale: float,
-    block_k: int = 512,
+    softcap: float = 0.0,
+    window: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
+    """Dense flash decode in the model's decode layout: the step's q and new
+    K/V rows with their query axis, and the stacked cache as it is held."""
     squeeze = q.ndim == 4
     if squeeze:
-        q = q[:, 0]
-    block_k = clamp_block(block_k, k_cache.shape[1])
-    k_cache = pad_to_multiple(k_cache, block_k, axis=1)
-    v_cache = pad_to_multiple(v_cache, block_k, axis=1)
+        q, k_new, v_new = q[:, 0], k_new[:, 0], v_new[:, 0]
     out = decode_attention(
-        q, k_cache, v_cache, valid_len,
-        scale=scale, block_k=block_k, interpret=interpret,
+        q, k_new, v_new, k_cache, v_cache, unit, lengths, active,
+        scale=scale, softcap=softcap, window=window, interpret=interpret,
     )
     return out[:, None] if squeeze else out
 

@@ -5,11 +5,24 @@ Cache convention
 A self-attention cache is a dict ``{"k": (B, L_max, n_kv, hd), "v": ...}``
 plus an external per-example ``lengths: (B,) int32`` giving the number of
 valid tokens already cached. A decode step attends over ``lengths + 1``
-entries, its new token's row laid over the cache at ``lengths`` for the
-attention alone, and returns that row: the model writes every layer's rows
-into the cache after its layer scan (``_write_at_lengths``). Cross-attention caches
-encoder K/V once at prefill; decode reuses them unchanged (the paper's
-vision-layer semantics).
+entries, the cached rows ``[0, lengths)`` and its new token's row, and
+returns that row: the model writes every layer's rows into the cache after
+its layer scan (``_write_at_lengths``). Which rows a dense decode step
+reads depends on the path:
+
+* the XLA path (``_decode_attend`` over ``_with_row_at_lengths``: the new
+  row laid over the cache at ``lengths``) reads all ``L_max`` rows of
+  every slot and masks the ones past ``lengths``. Paged decode, heads
+  narrower than the TPU's 128 lanes, and every platform but the TPU take
+  it;
+* on the TPU, where ``decode_reads_stack(cfg)``, the layer scan hands the
+  step the whole stacked cache and the layer's index, and the flash-decode
+  kernel (``kernels/decode_attn``) streams only the blocks that hold each
+  active slot's ``lengths`` rows (``kv_rows_read``), straight out of the
+  stack, and folds the new row in on its own.
+
+Cross-attention caches encoder K/V once at prefill; decode reuses them
+unchanged (the paper's vision-layer semantics).
 
 GQA is computed grouped: queries are reshaped to (B, S, n_kv, group, hd) so
 the kv tensors are never materialised repeated — the same trick the fused
@@ -23,8 +36,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.decode_attn import fold_vmapped_axis, gqa_decode_attention, kv_rows_read
 from repro.models.flash import attention_prefill_auto
 from repro.models.layers import apply_rope, softcap_logits
+from repro.models.unroll import unroll_enabled
 
 NEG_INF = -2.3819763e38  # large negative, safe in bf16/fp32
 
@@ -216,17 +231,51 @@ def _write_at_lengths(buf: jax.Array, new: jax.Array, lengths: jax.Array) -> jax
     row, which XLA writes in place when ``buf`` is donated, in any device
     layout (a scatter into a leaf narrower than 128 lanes, whose sequence
     axis the device lays out minor, copies the whole leaf first). A slot at
-    ``lengths == L`` puts back the row it would clip to.
+    ``lengths == L`` puts back the row it would clip to. Under ``jax.vmap``
+    (replicas batched on one device) the loop runs over every replica's
+    slots with scalar indices: a vmapped dynamic-slice would be a gather,
+    for which XLA lays the whole cache out anew.
     """
-    n_units, l, tail = buf.shape[0], buf.shape[2], buf.shape[3:]
+    return _write_one(buf, new, lengths)
 
-    def write(b, buf):
-        at = (0, b, jnp.minimum(lengths[b], l - 1)) + (0,) * len(tail)
-        old = jax.lax.dynamic_slice(buf, at, (n_units, 1, 1) + tail)
-        row = jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1).astype(buf.dtype)
-        return jax.lax.dynamic_update_slice(buf, jnp.where(lengths[b] < l, row, old), at)
 
-    return jax.lax.fori_loop(0, buf.shape[1], write, buf)
+def _write_slots(buf: jax.Array, new: jax.Array, lengths: jax.Array) -> jax.Array:
+    """The loop of ``_write_at_lengths`` over ``buf``'s slots, or, with a
+    leading replica axis on all three (``lengths`` (R, B)), over every
+    replica's slots."""
+    rep = lengths.ndim - 1
+    n_units, b, l = buf.shape[rep:rep + 3]
+    tail = (0,) * (buf.ndim - rep - 3)
+    size = (1,) * rep + (n_units, 1, 1) + buf.shape[rep + 3:]
+
+    def write(i, buf):
+        r = (i // b,) if rep else ()
+        n = lengths[r + (i % b,)]
+        at = r + (0, i % b, jnp.minimum(n, l - 1)) + tail
+        old = jax.lax.dynamic_slice(buf, at, size)
+        row = jax.lax.dynamic_slice(new, r + (0, i % b, 0) + tail, size)
+        return jax.lax.dynamic_update_slice(
+            buf, jnp.where(n < l, row.astype(buf.dtype), old), at)
+
+    return jax.lax.fori_loop(0, lengths.size, write, buf)
+
+
+# the vmapped axis becomes the replica axis, then folds into it
+_write_one = jax.custom_batching.custom_vmap(_write_slots)
+_write_replicas = jax.custom_batching.custom_vmap(_write_slots)
+
+
+@_write_one.def_vmap
+def _write_one_vmapped(n, batched, buf, new, lengths):
+    lead = lambda x, bat: x if bat else jnp.broadcast_to(x, (n,) + x.shape)
+    return _write_replicas(*map(lead, (buf, new, lengths), batched)), True
+
+
+@_write_replicas.def_vmap
+def _write_replicas_vmapped(n, batched, buf, new, lengths):
+    out = _write_replicas(*(fold_vmapped_axis(x, bat, n)
+                            for x, bat in zip((buf, new, lengths), batched)))
+    return out.reshape((n, -1) + out.shape[1:]), True
 
 
 @jax.named_scope("attn")
@@ -270,6 +319,42 @@ def _decode_attend(params, q, k_buf, v_buf, lengths, cfg, is_global, out_dtype):
     return jnp.einsum("bshk,hkd->bsd", ctx, params["wo"])
 
 
+def decode_reads_stack(cfg) -> bool:
+    """Whether a dense decode step hands this config's attention the whole
+    stacked cache and the layer's index, for the TPU kernel: heads of a
+    multiple of the 128 lanes and KV heads of a multiple of the 8
+    sublanes, in the scanned program (not the unrolled accounting
+    lowering). A narrower leaf is laid out with its sequence axis minor,
+    which the kernel would relayout every step; the kernel views a block's
+    (rows, KV, hd) as (rows * KV, hd), a bitcast only where the KV heads
+    fill the sublane tile (and Mosaic refuses the new row's scores for a
+    single KV head)."""
+    return (cfg.head_dim > 0 and cfg.head_dim % 128 == 0 and cfg.n_kv_heads % 8 == 0
+            and not unroll_enabled())
+
+
+def decode_rows_read(cfg, platform: str, lengths, active, max_len: int):
+    """Rows of each slot's dense cache a decode step's attention streams on
+    ``platform``, from the step's ``lengths`` and ``active`` (NumPy): the
+    kernel's (``kv_rows_read``) where it runs, else all ``max_len``."""
+    if platform == "tpu" and decode_reads_stack(cfg):
+        return kv_rows_read(lengths, active, max_len)
+    return np.full(np.shape(lengths), max_len)
+
+
+@jax.named_scope("attn")
+def _decode_attend_kernel(params, q, k_new, v_new, cache, unit, lengths, active,
+                          cfg, is_global, out_dtype):
+    """``_decode_attend`` over ``_with_row_at_lengths`` by the flash-decode
+    kernel, reading layer ``unit`` of the stacked cache in place."""
+    ctx = gqa_decode_attention(
+        q, k_new, v_new, cache["k"], cache["v"], unit, lengths, active,
+        scale=_attn_scale(cfg), softcap=cfg.attn_softcap,
+        window=0 if is_global else cfg.sliding_window,
+    )
+    return jnp.einsum("bshk,hkd->bsd", ctx.astype(out_dtype), params["wo"])
+
+
 def self_attention_decode(
     params: Dict,
     x: jax.Array,                    # (B, 1, d)
@@ -278,16 +363,33 @@ def self_attention_decode(
     cfg,
     *,
     is_global: bool,
+    unit: Optional[jax.Array] = None,
+    active: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict]:
     """One decode step over a dense cache, read only: returns the output
     and the new token's K/V rows ``{"k", "v"}: (B, 1, KV, hd)``, which the
-    caller writes at ``lengths``."""
+    caller writes at ``lengths``. With ``unit``, ``cache`` is the whole
+    stack ``(n_units, B, L, KV, hd)`` and the step reads layer ``unit``:
+    by the kernel on the TPU, which reads no row of a slot that is not
+    ``active`` (default: every slot), by the XLA path elsewhere."""
     q, k_new, v_new = _decode_qkv(params, x, lengths, cfg)
     k_new = k_new.astype(cache["k"].dtype)
     v_new = v_new.astype(cache["v"].dtype)
-    k_buf = _with_row_at_lengths(cache["k"], k_new, lengths)
-    v_buf = _with_row_at_lengths(cache["v"], v_new, lengths)
-    out = _decode_attend(params, q, k_buf, v_buf, lengths, cfg, is_global, x.dtype)
+
+    def xla(cache):
+        k_buf = _with_row_at_lengths(cache["k"], k_new, lengths)
+        v_buf = _with_row_at_lengths(cache["v"], v_new, lengths)
+        return _decode_attend(params, q, k_buf, v_buf, lengths, cfg, is_global, x.dtype)
+
+    if unit is None:
+        out = xla(cache)
+    else:
+        if active is None:
+            active = jnp.ones(lengths.shape, bool)
+        out = jax.lax.platform_dependent(
+            tpu=lambda: _decode_attend_kernel(params, q, k_new, v_new, cache, unit,
+                                              lengths, active, cfg, is_global, x.dtype),
+            default=lambda: xla({n: c[unit] for n, c in cache.items()}))
     return out, {"k": k_new, "v": v_new}
 
 

@@ -11,7 +11,7 @@ Public API (all pure functions):
     paged_layout(cfg)                      -> bool pytree (paged vs slot leaves)
     forward(params, cfg, tokens/embeds, enc_states=None)       # train: (B,S,d) final hidden
     prefill(params, cfg, tokens, cache, enc_states=None)       # -> (last_logits, cache, lengths)
-    decode_step(params, cfg, token, cache, lengths, enc_states_cacheed)  # -> (logits, cache)
+    decode_step(params, cfg, token, cache, lengths, enc_states=None, active=None)  # -> (logits, cache, lengths+1)
     decode_step_paged(params, cfg, token, cache, lengths, active, block_tables)
 
 Depth is organised as ``cfg.stages``: each stage scans ``n_units`` copies of
@@ -208,6 +208,10 @@ PAGED_KINDS = ("attn", "attn_global", "shared_attn", "mla", "mla_moe")
 # Block kinds whose O(1) state a decode step replaces whole: the decode scan
 # carries their stacked state and writes each unit's in place.
 STATE_KINDS = ("ssm", "ssm_mlp", "gdn")
+# Block kinds whose dense decode reads its layer out of the whole stacked
+# cache where ``attn.decode_reads_stack`` holds: the decode scan closes over
+# the stack and passes the layer's index instead of slicing the layer out.
+STACKED_KINDS = ("attn", "attn_global", "shared_attn")
 
 
 def _block_paged_cache(kind: str, cfg: ModelConfig, batch: int, n_pages: int,
@@ -281,11 +285,15 @@ def _block_apply(
     enc_states: Optional[jax.Array],
     block_tables: Optional[jax.Array] = None,   # paged decode only
     prefix_len: Optional[jax.Array] = None,     # suffix prefill only
+    unit: Optional[jax.Array] = None,           # dense decode over a stacked cache
+    active: Optional[jax.Array] = None,         # with ``unit``: the live slots
 ) -> Tuple[jax.Array, Optional[Dict]]:
     """One block: ``(x, what it writes)``. In train and prefill mode that is
     the block's new cache; in decode mode it is what ``_decode_write``
     persists after the layer scan: the new token's rows for a per-token
-    cache, the whole new state for ssm/gdn, nothing for cross-attention."""
+    cache, the whole new state for ssm/gdn, nothing for cross-attention.
+    With ``unit``, an attention block's ``cache`` is its whole stack and it
+    reads layer ``unit`` of it (``attn.self_attention_decode``)."""
     if kind == "shared_attn":
         bp = shared_params
         kind_eff = "attn_global"
@@ -307,7 +315,8 @@ def _block_apply(
             )
         elif mode == "decode":
             a_out, new_cache = attn.self_attention_decode(
-                bp["attn"], h, cache, lengths, cfg, is_global=is_global
+                bp["attn"], h, cache, lengths, cfg, is_global=is_global,
+                unit=unit, active=active,
             )
         elif mode == "prefill" and prefix_len is not None:
             a_out, new_cache = attn.self_attention_prefill_suffix(
@@ -457,7 +466,11 @@ def _run_stages(
     are written once, in place, afterwards (``_decode_write``); the stacked
     state of ``STATE_KINDS`` rides in the scan's carry and each unit reads
     its own and writes it back in place (``_state_read``, ``_state_write``).
-    So no cache is copied whole.
+    A dense decode over ``STACKED_KINDS`` with ``attn.decode_reads_stack``
+    does not slice its layer out of the scan's ``xs``: the scan closes over
+    the stack and each unit reads its layer by index, which the TPU kernel
+    does in place, only as far as each slot's rows go (``active`` marks the
+    slots that have any). So no cache is copied whole.
     ``lengths`` in prefill: the prompts' true lengths (recurrent blocks keep
     padding out of their state)."""
     shared = params.get("shared_block")
@@ -465,12 +478,16 @@ def _run_stages(
     for si, stage in enumerate(cfg.stages):
         sp = params["stages"][si]
         sc = cache["stages"][si] if cache is not None else None
+        scanned = mode == "decode" and sc is not None and not unroll_enabled()
         carried = tuple(f"b{i}" for i, kind in enumerate(stage.unit)
-                        if kind in STATE_KINDS) \
-            if mode == "decode" and sc is not None and not unroll_enabled() else ()
+                        if kind in STATE_KINDS) if scanned else ()
+        stacked = tuple(f"b{i}" for i, kind in enumerate(stage.unit)
+                        if kind in STACKED_KINDS) \
+            if scanned and block_tables is None and attn.decode_reads_stack(cfg) else ()
 
-        def unit_fn(carry, xs, _stage=stage, _carried=carried):
-            if _carried:        # ((x, stacked states), (params, caches, unit))
+        def unit_fn(carry, xs, _stage=stage, _carried=carried, _stacked=stacked,
+                    _sc=sc):
+            if _carried or _stacked:  # ((x, stacked states), (params, caches, unit))
                 (carry_x, states), (up, uc, u) = carry, xs
                 states = dict(states)
             else:
@@ -480,11 +497,14 @@ def _run_stages(
                 b = f"b{i}"
                 if b in _carried:
                     bc = _state_read(kind, states[b], u)
+                elif b in _stacked:
+                    bc = _sc[b]
                 else:
                     bc = uc[b] if uc is not None else None
                 carry_x, nbc = _block_apply(
                     kind, up[b], carry_x, cfg, mode, bc, lengths, shared,
                     enc_states, block_tables, prefix_len,
+                    unit=u if b in _stacked else None, active=active,
                 )
                 if b in _carried:
                     states[b] = _state_write(kind, states[b], nbc, u)
@@ -493,7 +513,7 @@ def _run_stages(
             # keep activations batch-sharded across unit boundaries (no-op
             # unless the launch layer configured batch axes)
             carry_x = constrain_batch(carry_x)
-            return ((carry_x, states) if _carried else carry_x), new_uc
+            return ((carry_x, states) if _carried or _stacked else carry_x), new_uc
 
         body = jax.checkpoint(unit_fn) if (remat and mode == "train") else unit_fn
         if unroll_enabled():
@@ -505,9 +525,9 @@ def _run_stages(
                 x, nuc = body(x, (up_u, uc_u))
                 new_units.append(nuc)
             new_sc = jax.tree.map(lambda *ls: jnp.stack(ls), *new_units)
-        elif carried:
+        elif carried or stacked:
             states = {b: sc[b] for b in carried}
-            rows = {b: c for b, c in sc.items() if b not in states}
+            rows = {b: c for b, c in sc.items() if b not in carried + stacked}
             (x, states), new_sc = jax.lax.scan(
                 body, (x, states), (sp, rows, jnp.arange(stage.n_units)))
             new_sc = {**new_sc, **states}
@@ -622,13 +642,17 @@ def decode_step(
     lengths: jax.Array,               # (B,) tokens already cached
     *,
     enc_states: Optional[jax.Array] = None,
+    active: Optional[jax.Array] = None,  # (B,) bool; default every slot
 ) -> Tuple[jax.Array, Dict, jax.Array]:
-    """One decode step: append token, return (logits (B,V), cache, lengths+1)."""
+    """One decode step: append token, return (logits (B,V), cache, lengths+1).
+    Attention may skip the cached rows of a slot that is not ``active``
+    (its logits are then not those of its cache)."""
     if cfg.input_is_embeddings:
         x = token.astype(_cdtype(cfg))
     else:
         x = _embed_inputs(params, cfg, token[:, None])
-    x, new_cache = _run_stages(params, cfg, x, "decode", cache, lengths, enc_states, False)
+    x, new_cache = _run_stages(params, cfg, x, "decode", cache, lengths, enc_states, False,
+                               active=active)
     x = rmsnorm(params["final_norm"], x, cfg.rms_eps)
     return logits(params, cfg, x)[:, 0], new_cache, lengths + 1
 

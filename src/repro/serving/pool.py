@@ -80,6 +80,7 @@ from repro.models import (
     prefill_suffix,
     recurrent_state_bytes,
 )
+from repro.models.attention import decode_rows_read
 from repro.models.config import ModelConfig
 from repro.serving.paged_cache import NULL_PAGE, BlockAllocator, TrafficCounter
 from repro.serving.prefix import PrefixHit, PrefixIndex, PrefixStats
@@ -186,7 +187,7 @@ def decode_impl_for(cfg: ModelConfig):
     def build():
         def decode_impl(params, tokens, cache, lengths, active, key, temperature):
             logits, new_cache, new_lengths = decode_step(
-                params, cfg, tokens, cache, lengths)
+                params, cfg, tokens, cache, lengths, active=active)
             next_tok = Pool._sample(logits, key, temperature)
             new_lengths = jnp.where(active, new_lengths, lengths)
             return next_tok, new_cache, new_lengths
@@ -476,6 +477,11 @@ class PhaseStats:
     # block-level HBM traffic behind decode_j (0 on dense/unmetered pools)
     decode_read_bytes: int = 0
     decode_write_bytes: int = 0
+    # dense decode steps: cache rows the attention streams, of the B x L
+    # rows each step holds (the TPU kernel's block-rounded rows of the
+    # active slots; every row on the XLA path)
+    decode_kv_rows_read: int = 0
+    decode_kv_rows_held: int = 0
     # lever state last applied to the pool that produced these stats
     configured_clock_mhz: float = 0.0
     actual_clock_mhz: float = 0.0
@@ -488,13 +494,16 @@ class PhaseStats:
         self.prefill_j += joules
 
     def merge_decode(self, tokens: int, secs: float, joules: float = 0.0,
-                     read_bytes: int = 0, write_bytes: int = 0):
+                     read_bytes: int = 0, write_bytes: int = 0,
+                     kv_rows_read: int = 0, kv_rows_held: int = 0):
         self.decode_tokens += tokens
         self.decode_s += secs
         self.decode_steps += 1
         self.decode_j += joules
         self.decode_read_bytes += read_bytes
         self.decode_write_bytes += write_bytes
+        self.decode_kv_rows_read += kv_rows_read
+        self.decode_kv_rows_held += kv_rows_held
 
     def note_operating_point(self, op: OperatingPoint):
         self.actual_clock_mhz = float(op.actual_clock_mhz)
@@ -529,6 +538,8 @@ class PhaseStats:
             decode_j=self.decode_j + other.decode_j,
             decode_read_bytes=self.decode_read_bytes + other.decode_read_bytes,
             decode_write_bytes=self.decode_write_bytes + other.decode_write_bytes,
+            decode_kv_rows_read=self.decode_kv_rows_read + other.decode_kv_rows_read,
+            decode_kv_rows_held=self.decode_kv_rows_held + other.decode_kv_rows_held,
             configured_clock_mhz=self.configured_clock_mhz,
             actual_clock_mhz=self.actual_clock_mhz,
             lever_engaged=self.lever_engaged,
@@ -726,6 +737,7 @@ class Pool:
         self._prefill_impl = prefill_impl_for(cfg, max_seq_len)
         self._decode_impl = decode_impl_for(cfg)
         self._decode_paged_impl = decode_paged_impl_for(cfg)
+        self.platform = device.platform if device is not None else jax.default_backend()
         self._jit_prefill = _cached(
             ("prefill_jit", cfg, max_seq_len),
             lambda: jax.jit(self._prefill_impl, static_argnames=("bucket",)))
@@ -1662,7 +1674,13 @@ class Pool:
             self.traffic.count_writes(n_active, write_total)
             self.traffic.count_step()
         step_j = sum(per_req_j.values()) if per_req_j else mj * n_active / 1e3
-        self.stats.merge_decode(n_active, dt, step_j, read_total, write_total)
+        rows_read = rows_held = 0
+        if not self.paged:
+            rows_held = self.max_batch * self.max_seq_len
+            rows_read = int(decode_rows_read(self.cfg, self.platform, pre["args"][3],
+                                             pre["active"], self.max_seq_len).sum())
+        self.stats.merge_decode(n_active, dt, step_j, read_total, write_total,
+                                rows_read, rows_held)
 
         now = self.clock()
         self._settled_s = now

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import (
+    block_rows,
     clamp_block,
     decode_attention,
     decode_attention_ref,
@@ -13,6 +14,7 @@ from repro.kernels import (
     gdn_scan_ref,
     gqa_decode_attention,
     gqa_paged_decode_attention,
+    kv_rows_read,
     largest_divisor_block,
     mla_fused_decode,
     mla_latent_decode,
@@ -29,59 +31,202 @@ from repro.kernels import (
 TOL = {jnp.float32: dict(rtol=5e-5, atol=5e-5), jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
 
 
+def _dense_case(key, b, h, kv, dk, dv, l, n_units=2, dtype=jnp.float32):
+    """Random operands of the dense kernel: q, the new rows, and a stacked
+    cache of ``n_units`` layers."""
+    ks = jax.random.split(key, 5)
+    q = jax.random.normal(ks[0], (b, h, dk)).astype(dtype)
+    k_new = jax.random.normal(ks[1], (b, kv, dk)).astype(dtype)
+    v_new = jax.random.normal(ks[2], (b, kv, dv)).astype(dtype)
+    k = jax.random.normal(ks[3], (n_units, b, l, kv, dk)).astype(dtype)
+    v = jax.random.normal(ks[4], (n_units, b, l, kv, dv)).astype(dtype)
+    return q, k_new, v_new, k, v
+
+
+def _dense_ref(q, k_new, v_new, k, v, unit, lengths, scale):
+    """The oracle over layer ``unit`` with each new row laid at ``lengths``:
+    attends to positions ``<= lengths`` (all ``L`` cached rows, and not the
+    new one, for a full slot)."""
+    l = k.shape[2]
+    at = (jnp.arange(l)[None, :] == lengths[:, None])[:, :, None, None]
+    kb = jnp.where(at, k_new[:, None], k[unit])
+    vb = jnp.where(at, v_new[:, None], v[unit])
+    return decode_attention_ref(q, kb, vb, jnp.minimum(lengths + 1, l), scale)
+
+
 class TestDecodeAttn:
     @pytest.mark.parametrize("b,h,kv,dk,dv,l,blk", [
-        (1, 4, 1, 16, 16, 64, 32),      # MQA
-        (2, 8, 2, 32, 16, 128, 64),     # GQA, asymmetric dv
-        (3, 6, 6, 16, 16, 96, 32),      # MHA
+        (1, 4, 1, 16, 16, 96, 32),      # MQA, 3 blocks
+        (2, 8, 2, 32, 16, 192, 64),     # GQA, asymmetric dv, 3 blocks
+        (3, 6, 6, 16, 16, 96, 32),      # MHA, 3 blocks
         (2, 4, 2, 64, 64, 256, 256),    # single block
     ])
     def test_shapes_sweep(self, b, h, kv, dk, dv, l, blk):
+        """Slot 0 holds ``blk - 1`` rows, the rest random lengths; the
+        cache is read in blocks of ``blk`` rows."""
+        assert block_rows(l) == blk
         key = jax.random.PRNGKey(b * 1000 + h)
-        q = jax.random.normal(key, (b, h, dk), jnp.float32)
-        k = jax.random.normal(jax.random.fold_in(key, 1), (b, l, kv, dk), jnp.float32)
-        v = jax.random.normal(jax.random.fold_in(key, 2), (b, l, kv, dv), jnp.float32)
-        vl = jax.random.randint(jax.random.fold_in(key, 3), (b,), 1, l + 1)
-        out = decode_attention(q, k, v, vl, scale=0.2, block_k=blk, interpret=True)
-        ref = decode_attention_ref(q, k, v, vl, scale=0.2)
+        q, k_new, v_new, k, v = _dense_case(key, b, h, kv, dk, dv, l)
+        vl = jax.random.randint(jax.random.fold_in(key, 3), (b,), 0, l + 1).at[0].set(blk - 1)
+        out = decode_attention(q, k_new, v_new, k, v, jnp.int32(1), vl, jnp.ones(b, bool),
+                               scale=0.2, interpret=True)
+        ref = _dense_ref(q, k_new, v_new, k, v, 1, vl, 0.2)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL[jnp.float32])
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_dtypes(self, dtype):
         key = jax.random.PRNGKey(9)
         b, h, kv, d, l = 2, 4, 2, 32, 128
-        q = jax.random.normal(key, (b, h, d)).astype(dtype)
-        k = jax.random.normal(jax.random.fold_in(key, 1), (b, l, kv, d)).astype(dtype)
-        v = jax.random.normal(jax.random.fold_in(key, 2), (b, l, kv, d)).astype(dtype)
-        vl = jnp.array([l, l // 2], jnp.int32)
-        out = decode_attention(q, k, v, vl, scale=0.18, block_k=64, interpret=True)
-        ref = decode_attention_ref(
-            q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32), vl, scale=0.18
-        )
+        args = _dense_case(key, b, h, kv, d, d, l, dtype=dtype)
+        vl = jnp.array([l - 1, l // 2], jnp.int32)
+        out = decode_attention(*args, jnp.int32(0), vl, jnp.ones(b, bool),
+                               scale=0.18, interpret=True)
+        ref = _dense_ref(*(a.astype(jnp.float32) for a in args), 0, vl, 0.18)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref), **TOL[dtype]
         )
 
     def test_wrapper_pads_nondivisible_length(self):
+        """The wrapper takes the model's query axis; a cache length that no
+        ``BLOCK_ROWS`` divides is read in blocks of a divisor (100: 4 rows),
+        with no padding."""
         key = jax.random.PRNGKey(11)
-        b, h, kv, d, l = 2, 4, 2, 16, 100   # 100 not a block multiple
-        q = jax.random.normal(key, (b, h, d))
-        k = jax.random.normal(jax.random.fold_in(key, 1), (b, l, kv, d))
-        v = jax.random.normal(jax.random.fold_in(key, 2), (b, l, kv, d))
-        vl = jnp.array([100, 37], jnp.int32)
-        out = gqa_decode_attention(q, k, v, vl, scale=0.25, block_k=32, interpret=True)
-        ref = decode_attention_ref(q, k, v, vl, scale=0.25)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=5e-5, atol=5e-5)
+        b, h, kv, d, l = 2, 4, 2, 16, 100
+        q, k_new, v_new, k, v = _dense_case(key, b, h, kv, d, d, l)
+        vl = jnp.array([99, 37], jnp.int32)
+        assert block_rows(l) == 4
+        out = gqa_decode_attention(q[:, None], k_new[:, None], v_new[:, None], k, v,
+                                   jnp.int32(1), vl, jnp.ones(b, bool),
+                                   scale=0.25, interpret=True)
+        assert out.shape == (b, 1, h, d)
+        ref = _dense_ref(q, k_new, v_new, k, v, 1, vl, 0.25)
+        np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(ref), rtol=5e-5, atol=5e-5)
 
     def test_single_valid_token(self):
+        """A slot with no cached row attends to its new row alone."""
         key = jax.random.PRNGKey(12)
         b, h, kv, d, l = 1, 2, 1, 16, 64
-        q = jax.random.normal(key, (b, h, d))
-        k = jax.random.normal(jax.random.fold_in(key, 1), (b, l, kv, d))
-        v = jax.random.normal(jax.random.fold_in(key, 2), (b, l, kv, d))
-        vl = jnp.array([1], jnp.int32)
-        out = decode_attention(q, k, v, vl, scale=1.0, block_k=32, interpret=True)
-        np.testing.assert_allclose(np.asarray(out)[0], np.asarray(v)[0, 0, 0][None].repeat(2, 0), rtol=1e-5)
+        q, k_new, v_new, k, v = _dense_case(key, b, h, kv, d, d, l)
+        out = decode_attention(q, k_new, v_new, k, v, jnp.int32(0), jnp.array([0], jnp.int32),
+                               jnp.ones(b, bool), scale=1.0, interpret=True)
+        np.testing.assert_allclose(np.asarray(out)[0], np.asarray(v_new)[0, 0][None].repeat(2, 0), rtol=1e-5)
+
+
+# the model's side of the dense kernel: heads of 16 lanes, group 4, two
+# blocks of 512 positions, three stacked layers
+DENSE_L, DENSE_UNITS = 1024, 3
+DENSE_CASES = {
+    "lengths": {},
+    "inactive": {"active": [True, False, True, False, True, True]},
+    "gqa_group4": {"lengths": [700, 3, 512, 0, 1023, 90]},
+    "nope_scale": {"cfg": {"attn_scale": 1 / 64, "use_rope": False}},
+    "softcap": {"cfg": {"attn_softcap": 30.0}},
+    "window": {"cfg": {"sliding_window": 300}, "is_global": False},
+    "unit0": {"unit": 0},
+    "last_unit": {"unit": DENSE_UNITS - 1},
+}
+
+
+@pytest.mark.parametrize("case", sorted(DENSE_CASES))
+def test_dense_kernel_matches_the_xla_decode_attention(case):
+    """The dense kernel over the stacked cache gives ``_decode_attend`` over
+    ``_with_row_at_lengths`` of its layer, the model's XLA path: lengths
+    0, 1, block - 1, block, L - 1 and L (a full slot that writes nothing);
+    inactive slots, whose stale lengths it does not read (each then attends
+    to its new row alone); grouped heads (4 q heads a KV head); a NoPE
+    config's scale; softcap; a sliding window; the first and last layer."""
+    import types
+
+    from repro.models import attention as A
+
+    spec = DENSE_CASES[case]
+    b, h, kv, d = 6, 8, 2, 16
+    blk = block_rows(DENSE_L)
+    cfg = types.SimpleNamespace(**{"head_dim": d, "attn_scale": None, "attn_softcap": 0.0,
+                                   "sliding_window": 0, **spec.get("cfg", {})})
+    is_global = spec.get("is_global", True)
+    unit = spec.get("unit", 1)
+    lengths = jnp.array(spec.get("lengths", [0, 1, blk - 1, blk, DENSE_L - 1, DENSE_L]),
+                        jnp.int32)
+    active = jnp.array(spec.get("active", [True] * b))
+    q, k_new, v_new, k, v = _dense_case(jax.random.PRNGKey(len(case)), b, h, kv, d, d,
+                                        DENSE_L, n_units=DENSE_UNITS)
+    got = gqa_decode_attention(
+        q[:, None], k_new[:, None], v_new[:, None], k, v, jnp.int32(unit), lengths, active,
+        scale=A._attn_scale(cfg), softcap=cfg.attn_softcap,
+        window=0 if is_global else cfg.sliding_window, interpret=True)[:, 0]
+
+    def xla(lengths):
+        wo = {"wo": jnp.eye(h * d).reshape(h, d, h * d)}     # ctx, flattened
+        kb = A._with_row_at_lengths(k[unit], k_new[:, None], lengths)
+        vb = A._with_row_at_lengths(v[unit], v_new[:, None], lengths)
+        out = A._decode_attend(wo, q[:, None], kb, vb, lengths, cfg, is_global, jnp.float32)
+        return np.asarray(out).reshape(b, h, d)
+
+    want = np.where(np.asarray(active)[:, None, None], xla(lengths),
+                    xla(jnp.zeros_like(lengths)))
+    np.testing.assert_allclose(np.asarray(got), want, **TOL[jnp.float32])
+
+
+def test_rows_read_follow_the_kernels_block_rule():
+    """``kv_rows_read``, behind the pool's ``decode_kv_rows_read`` counter,
+    names the rows the kernel streams: a NaN in the first row past them
+    leaves a slot's output as it was, and one in the last row inside them,
+    masked or not, reaches it (0 x NaN is NaN in the p @ V product)."""
+    b, h, kv, d, l = 5, 4, 2, 16, 1024
+    blk = block_rows(l)
+    q, k_new, v_new, k, v = _dense_case(jax.random.PRNGKey(5), b, h, kv, d, d, l)
+    lengths = jnp.array([0, 1, blk, blk + 1, l], jnp.int32)
+    active = jnp.array([True, True, True, False, True])
+    rows = np.asarray(kv_rows_read(lengths, active, l))
+    np.testing.assert_array_equal(rows, [0, blk, blk, 0, l])
+    np.testing.assert_array_equal(kv_rows_read(np.asarray(lengths), np.asarray(active), l), rows)
+    run = lambda v: np.asarray(decode_attention(
+        q, k_new, v_new, k, v, jnp.int32(0), lengths, active, scale=0.3, interpret=True))
+    clean = run(v)
+    for i in range(b):
+        if rows[i] < l:
+            past = run(v.at[0, i, rows[i]].set(jnp.nan))
+            np.testing.assert_array_equal(past, clean)
+        if rows[i] > 0:
+            inside = run(v.at[0, i, rows[i] - 1].set(jnp.nan))
+            assert np.isnan(inside[i]).all()
+            np.testing.assert_array_equal(np.delete(inside, i, 0), np.delete(clean, i, 0))
+
+
+@pytest.mark.parametrize("case", ["replicas", "shared_cache", "unit_per_replica", "nested"])
+def test_vmapped_kernel_matches_per_replica_calls(case):
+    """Under ``jax.vmap`` (replicas batched on one device) the dense kernel
+    folds the vmapped axis into its slots and gives each replica's own
+    call: every replica's cache and lengths (one slot inactive), a cache
+    shared by the batch, a layer per replica, and a vmap inside a vmap."""
+    k, b, h, kv, d, l, n_units = 3, 4, 8, 2, 16, 96, 2
+    keys = jax.random.split(jax.random.PRNGKey(21), k)
+    q, k_new, v_new, kc, vc = (jnp.stack(x) for x in zip(
+        *(_dense_case(kk, b, h, kv, d, d, l, n_units=n_units) for kk in keys)))
+    lengths = jax.random.randint(jax.random.PRNGKey(22), (k, b), 0, l + 1)
+    active = jnp.ones((k, b), bool).at[1, 2].set(False)
+    units = jnp.array([1, 0, 1], jnp.int32)
+    call = lambda q, kn, vn, kc, vc, u, n, a: decode_attention(
+        q, kn, vn, kc, vc, u, n, a, scale=0.25, interpret=True)
+    ax = (0, 0, 0, 0, 0, None, 0, 0)
+    if case == "shared_cache":
+        ax, kc, vc = (0, 0, 0, None, None, None, 0, 0), kc[0], vc[0]
+    if case == "unit_per_replica":
+        ax = (0,) * 8
+    pick = lambda x, i, a: x if a is None else x[i]
+    u = units if case == "unit_per_replica" else jnp.int32(1)
+    args = (q, k_new, v_new, kc, vc, u, lengths, active)
+    want = np.stack([call(*(pick(x, i, a) for x, a in zip(args, ax))) for i in range(k)])
+    if case == "nested":
+        two = lambda x: jnp.stack([x, x[::-1]])
+        got = jax.vmap(jax.vmap(call, in_axes=ax), in_axes=ax)(
+            *(x if a is None else two(x) for x, a in zip(args, ax)))
+        got, want = np.asarray(got), np.stack([want, want[::-1]])
+        on = np.stack([np.asarray(active), np.asarray(active)[::-1]])
+    else:
+        got, on = np.asarray(jax.vmap(call, in_axes=ax)(*args)), np.asarray(active)
+    np.testing.assert_array_equal(got[on], want[on])
 
 
 def _random_tables(key, b, nb, n_pages, valid_blocks):
@@ -133,7 +278,12 @@ class TestPagedDecodeAttn:
         out = paged_decode_attention(q, kp, vp, tables, vl, scale=0.18, interpret=True)
         k_dense = kp[tables].reshape(b, nb * bs, kv, d)
         v_dense = vp[tables].reshape(b, nb * bs, kv, d)
-        ref = decode_attention(q, k_dense, v_dense, vl, scale=0.18, block_k=bs, interpret=True)
+        # the dense kernel over the same rows: the last one is its new row
+        last = (vl - 1)[:, None, None, None]
+        new_row = lambda x: jnp.take_along_axis(x, last, axis=1)[:, 0]
+        ref = decode_attention(q, new_row(k_dense), new_row(v_dense), k_dense[None],
+                               v_dense[None], jnp.int32(0), vl - 1, jnp.ones(b, bool),
+                               scale=0.18, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=5e-5, atol=5e-5)
 
     def test_wrapper_accepts_query_seq_axis(self):

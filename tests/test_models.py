@@ -58,6 +58,9 @@ CASES = {
                     attn_scale=1 / 16, embedding_multiplier=12.0,
                     residual_multiplier=0.22, logits_scaling=8.0),
     "audio": tiny([(("attn",), 2)], family="audio", input_is_embeddings=True),
+    # heads of the TPU's 128 lanes, 8 KV heads: dense decode reads each
+    # layer out of the stacked cache by index (the kernel's path on the TPU)
+    "gqa128": tiny([(("attn",), 2)], n_heads=16, n_kv_heads=8, head_dim=128),
 }
 
 
@@ -280,6 +283,64 @@ def test_row_writes_match_the_formula(width):
     np.testing.assert_array_equal(np.asarray(got[:, 1]), np.asarray(buf[:, 1]))
 
 
+@pytest.mark.parametrize("case", ["replicas", "nested", "shared_lengths"])
+def test_vmapped_row_writes_match_per_replica_writes(case):
+    """Under ``jax.vmap`` (the event engine's fused decode over replicas)
+    ``_write_at_lengths`` writes each replica's rows as its own call does,
+    bit for bit, also in a vmap inside a vmap and with lengths the
+    replicas share."""
+    from repro.models import attention
+
+    k = 3
+    buf = jax.random.normal(jax.random.PRNGKey(3), (k, 2, 3, L_DECODE, 2, 128))
+    new = jax.random.normal(jax.random.PRNGKey(4), (k, 2, 3, 1, 2, 128))
+    lengths = jnp.array([[2, L_DECODE, 0], [5, 1, L_DECODE], [0, 3, 4]], jnp.int32)
+    write = attention._write_at_lengths
+    if case == "shared_lengths":
+        got = jax.vmap(write, in_axes=(0, 0, None))(buf, new, lengths[0])
+        want = [write(buf[i], new[i], lengths[0]) for i in range(k)]
+    elif case == "nested":
+        two = lambda x: jnp.stack([x, x[::-1]])
+        got = jax.vmap(jax.vmap(write))(two(buf), two(new), two(lengths))
+        one = [write(buf[i], new[i], lengths[i]) for i in range(k)]
+        want = [jnp.stack(one), jnp.stack(one[::-1])]
+    else:
+        got = jax.vmap(write)(buf, new, lengths)
+        want = [write(buf[i], new[i], lengths[i]) for i in range(k)]
+    np.testing.assert_array_equal(np.asarray(got), np.stack(want))
+
+
+def test_vmapped_decode_kernel_path_matches_each_replicas_step(monkeypatch):
+    """Replicas' dense decode steps batched by ``vmap_replicas`` on the TPU
+    branch (the kernel interpreted here) give each replica's own step for
+    its active slots: logits and caches."""
+    from repro.kernels.decode_attn import ops
+    from repro.models import attention
+
+    cfg = CASES["gqa128"]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    k = 2
+    caches = [_random_cache(cfg, 4, 5 + i) for i in range(k)]
+    tokens = jnp.stack([_decode_token(cfg, 4, 7 + i) for i in range(k)])
+    lengths = jnp.array([[0, 3, 5, 6], [2, 6, 1, 4]], jnp.int32)
+    active = jnp.array([[True, True, False, True], [True, True, True, False]])
+    monkeypatch.setattr(attention, "gqa_decode_attention",
+                        functools.partial(ops.gqa_decode_attention, interpret=True))
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
+    step = lambda p, t, c, n, a: decode_step(p, cfg, t, c, n, active=a)
+    got_lg, got_cache, _ = M.vmap_replicas(step, 5)(
+        params, tokens, jax.tree.map(lambda *x: jnp.stack(x), *caches), lengths, active)
+    for i in range(k):
+        want_lg, want_cache, _ = step(params, tokens[i], caches[i], lengths[i], active[i])
+        on = np.asarray(active[i])
+        np.testing.assert_allclose(np.asarray(got_lg[i])[on], np.asarray(want_lg)[on],
+                                   rtol=2e-5, atol=2e-5)
+        jax.tree.map(lambda g, w: np.testing.assert_allclose(
+            np.asarray(g[i])[:, on], np.asarray(w)[:, on], rtol=2e-5, atol=2e-5),
+            got_cache, want_cache)
+
+
 def test_a_stale_row_write_shows_on_the_next_step(monkeypatch):
     """The seam the benchmark's fault check relies on: with
     ``attention._write_at_lengths`` made to return the buffer unchanged,
@@ -409,3 +470,37 @@ def test_prefill_at_a_padded_bucket_matches_the_exact_length(name):
                 assert close(g[k], w[k]), (i, k)
     assert close(got[0], want[0]) and close(got[2], want[2])
     assert not close(blind[2], want[2])
+
+
+@pytest.mark.parametrize("lengths", [[0, 3, 5, 6], [2, 1, 6, 4]], ids=["edges", "ragged"])
+def test_decode_kernel_path_matches_the_xla_path(monkeypatch, lengths):
+    """A dense decode step on the TPU's branch (the flash-decode kernel, run
+    by its interpreter here, reading each layer out of the stacked cache)
+    gives the XLA path's logits and caches for the active slots: cached
+    lengths 0 to ``max_len`` across blocks of 2 rows. The inactive slot
+    reads no row, so from the second layer on it writes other rows than
+    the XLA path, into a slot that holds no request."""
+    from repro.kernels.decode_attn import ops
+    from repro.models import attention
+
+    cfg = CASES["gqa128"]
+    assert attention.decode_reads_stack(cfg)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    cache = _random_cache(cfg, 4, 5)
+    token = _decode_token(cfg, 4, 7)
+    lengths = jnp.array(lengths, jnp.int32)
+    active = jnp.array([True, True, False, True])
+    want_lg, want_cache, _ = decode_step(params, cfg, token, cache, lengths)
+
+    kernel = ops.gqa_decode_attention
+    monkeypatch.setattr(attention, "gqa_decode_attention",
+                        functools.partial(kernel, interpret=True))
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
+    got_lg, got_cache, _ = decode_step(params, cfg, token, cache, lengths, active=active)
+    on = np.asarray(active)
+    np.testing.assert_allclose(np.asarray(got_lg)[on], np.asarray(want_lg)[on],
+                               rtol=2e-5, atol=2e-5)
+    jax.tree.map(lambda g, w: np.testing.assert_allclose(
+        np.asarray(g)[:, on], np.asarray(w)[:, on], rtol=2e-5, atol=2e-5),
+        got_cache, want_cache)

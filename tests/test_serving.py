@@ -40,6 +40,47 @@ class TestEngine:
         assert s.decode_steps >= 3
         assert s.prefill_s > 0 and s.decode_s > 0
 
+    @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+    def test_decode_kv_rows_count_what_attention_reads(self, setup, kernel):
+        """Each dense decode step holds B x L cache rows. The XLA path reads
+        them all; where the model's attention is the TPU kernel (a pool on a
+        TPU, said by hand here, with heads of 128 lanes and 8 KV heads) it
+        reads ``kv_rows_read`` of the lengths and active mask the step was
+        dispatched with."""
+        import dataclasses
+
+        from repro.kernels.decode_attn import kv_rows_read
+        from repro.serving.pool import PhaseStats
+
+        cfg, params = setup
+        if kernel:
+            cfg = dataclasses.replace(cfg, n_heads=8, n_kv_heads=8, head_dim=128)
+            params = init_params(cfg, jax.random.PRNGKey(0))
+        eng = ServingEngine(cfg, params, max_batch=2, max_seq_len=64)
+        pool = eng.pool
+        if kernel:
+            pool.platform = "tpu"
+        step, sent = pool._jit_decode, []
+
+        def dispatch(*args):
+            sent.append((np.array(args[3]), np.array(args[4])))
+            return step(*args)
+
+        pool._jit_decode = dispatch
+        for p in make_prompts(cfg, 3, 4, 10, seed=3):
+            eng.submit(p, max_new_tokens=4)
+        eng.run_to_completion()
+        s = eng.stats
+        assert s.decode_steps == len(sent) >= 3
+        assert s.decode_kv_rows_held == len(sent) * 2 * 64
+        read = sum(int(kv_rows_read(n, a, 64).sum()) for n, a in sent)
+        assert 0 < read < s.decode_kv_rows_held
+        assert s.decode_kv_rows_read == (read if kernel else s.decode_kv_rows_held)
+        both = s.merged_with(s)
+        assert (both.decode_kv_rows_read, both.decode_kv_rows_held) == (
+            2 * s.decode_kv_rows_read, 2 * s.decode_kv_rows_held)
+        assert PhaseStats().decode_kv_rows_held == 0
+
     def test_engine_matches_manual_greedy_decode(self, setup):
         """The engine's batched/continuous path produces the same greedy
         tokens as a manual single-request prefill+decode loop."""

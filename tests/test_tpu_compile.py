@@ -63,10 +63,11 @@ def _kernel_case(name, s):
     """(wrapper, args, kwargs) of one kernel at its paper widths."""
     B, L, nb = BATCH, 4096, 4096 // PAGE
     P = B * nb + 1
-    if name == "gqa_decode":          # qwen3-4b: H 32, KV 8, hd 128
+    if name == "gqa_decode":          # qwen3-4b: H 32, KV 8, hd 128, 36 layers
         return K.gqa_decode_attention, (
-            s((B, 1, 32, 128)), s((B, L, 8, 128)), s((B, L, 8, 128)),
-            s((B,), I32)), {"scale": 128 ** -0.5}
+            s((B, 1, 32, 128)), s((B, 1, 8, 128)), s((B, 1, 8, 128)),
+            s((36, B, L, 8, 128)), s((36, B, L, 8, 128)), s((), I32),
+            s((B,), I32), s((B,), jnp.bool_)), {"scale": 128 ** -0.5}
     if name == "gqa_paged_decode":
         return K.gqa_paged_decode_attention, (
             s((B, 1, 32, 128)), s((P, PAGE, 8, 128)), s((P, PAGE, 8, 128)),
@@ -128,7 +129,8 @@ def _writes_cache_in_place(compiled, cache, n_layers):
 def test_qwen3_decode_step_fits_one_chip(one_chip, paged):
     """The pool's serial decode program at the smoke's pool shape compiles
     for one v5e chip with its cache donated, fits its memory, and writes
-    its new rows into the donated cache in place."""
+    its new rows into the donated cache in place. The dense one's attention
+    is the flash-decode kernel, reading the stacked cache where it lies."""
     from repro.serving.pool import decode_jit_for
 
     cfg = get_config("qwen3-4b")
@@ -148,13 +150,15 @@ def test_qwen3_decode_step_fits_one_chip(one_chip, paged):
     compiled = decode_jit_for(cfg, paged=paged).lower(*args).compile()
     _fits(compiled, _tree_bytes(cache))
     _writes_cache_in_place(compiled, cache, cfg.n_blocks)
+    assert ("tpu_custom_call" in compiled.as_text()) == (not paged)
 
 
 def test_granite_decode_step_fits_one_chip(one_chip):
     """granite-4.0-h-micro's decode program at the benchmark cell's pool
     (batch 32, max_seq_len 2048): its 2.42 GB of stacked Mamba2 state and
     its KV cache are donated and written in place, with no second copy:
-    scratch under one layer's state at batch 32 (76 MB x 32 / 36)."""
+    scratch under one layer's state at batch 32 (76 MB x 32 / 36). Its
+    64-wide heads keep the XLA attention: no kernel in the program."""
     from repro.serving.pool import decode_jit_for
 
     cfg = get_config("granite-4.0-h-micro")
@@ -173,6 +177,51 @@ def test_granite_decode_step_fits_one_chip(one_chip):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < state // n_ssm, f"{temp} B of scratch"
     _writes_cache_in_place(compiled, cache, cfg.n_blocks)
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_gemma_mqa_decode_step_keeps_the_xla_attention(one_chip):
+    """gemma-2b's single KV head (256 lanes wide) does not fill the
+    kernel's sublane tile, and Mosaic refuses the kernel for it: its dense
+    decode program compiles for one v5e with the XLA attention, in place."""
+    from repro.serving.pool import decode_jit_for
+
+    cfg = get_config("gemma-2b")
+    s = _shapes(one_chip)
+    on_chip = lambda tree: jax.tree.map(lambda x: s(x.shape, x.dtype), tree)
+    cache = on_chip(abstract_cache(cfg, BATCH, SEQ))
+    args = (on_chip(abstract_params(cfg)), s((BATCH,), I32), cache, s((BATCH,), I32),
+            s((BATCH,), jnp.bool_), s((2,), jnp.uint32), s((BATCH,), F32))
+    compiled = decode_jit_for(cfg).lower(*args).compile()
+    _fits(compiled, _tree_bytes(cache))
+    _writes_cache_in_place(compiled, cache, cfg.n_blocks)
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_qwen3_vmap_decode_reads_each_replicas_cache_in_place(one_chip):
+    """The event engine's fused decode on one chip (plain vmap over two
+    replicas' banks, its default layout): the kernel runs over both
+    replicas' slots and reads each replica's stacked cache where it lies,
+    so no replica's cache is sliced out or copied."""
+    from repro.serving.events import _batched_core
+    from repro.serving.pool import decode_impl_for
+
+    cfg = get_config("qwen3-4b")
+    k = 2
+    s = _shapes(one_chip)
+    bank = jax.tree.map(lambda x: s((k,) + x.shape, x.dtype), abstract_cache(cfg, BATCH, SEQ))
+    impl = decode_impl_for(cfg)
+
+    def fused(params, cache, toks, lengths, active, keys, temps):
+        return _batched_core(impl, None)(params, toks, cache, lengths, active, keys, temps)
+
+    params = jax.tree.map(lambda x: s(x.shape, x.dtype), abstract_params(cfg))
+    compiled = jax.jit(fused, donate_argnums=(1,)).lower(
+        params, bank, s((k, BATCH), I32), s((k, BATCH), I32), s((k, BATCH), jnp.bool_),
+        s((k, 2), jnp.uint32), s((k, BATCH), F32)).compile()
+    _fits(compiled, _tree_bytes(bank))
+    _writes_cache_in_place(compiled, bank, cfg.n_blocks)
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_qwen3_mesh_decode_fits_four_chips(topo):
